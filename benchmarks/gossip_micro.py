@@ -1,6 +1,12 @@
 """Microbenchmarks of the gossip/optimizer hot path (CPU wall-clock; the
 derived column carries the analytically modeled TPU HBM-traffic ratio).
 
+Every multi-device sweep re-execs this module in a child pinned to
+``JAX_PLATFORMS=cpu`` with a forced host device count: those children are
+CPU counting benches by design (permutes, launches, modeled bytes), and
+pinning keeps them off any accelerator — a child that reached for a chip
+its parent holds would fail or hang.  None of these times is a chip time.
+
 Three parts:
 
 * in-process engine benches on the current device set (dense vs shifts,
@@ -505,11 +511,12 @@ def _e2e_loss_traj(model, batch, mesh, axes, A, overlap, steps: int = 8):
 
 def _bench_subprocess(argv: List[str], marker: str, devices: int,
                       label: str, extra_env: Dict | None = None):
-    """Re-exec this module with a forced host-platform device count and
-    parse the marker-prefixed JSON line — the one subprocess wrapper
-    behind every multi-device sweep (XLA_FLAGS must be set before jax
-    initializes, so the sweeps cannot run in-process)."""
+    """Re-exec this module on the CPU platform with a forced host device
+    count and parse the marker-prefixed JSON line — the one subprocess
+    wrapper behind every multi-device sweep (XLA_FLAGS must be set before
+    jax initializes, so the sweeps cannot run in-process)."""
     env = {**os.environ,
+           "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
            "PYTHONPATH": os.path.join(REPO, "src")
            + (os.pathsep + os.environ["PYTHONPATH"]

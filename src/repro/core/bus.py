@@ -354,45 +354,48 @@ def pack_tree(layout: BusLayout, tree: Any) -> jax.Array:
     buffer in bus dtype.  Pure jnp; pad elements are zero.  Segments are
     emitted in physical row order (slot rows are not monotone in flatten
     order once the layout is grouped), with zero-fill for every group's
-    tail pad."""
+    tail pad.  Each segment is shaped ``(A, slot_rows, 128)`` before the
+    row-axis concatenate, so the bus is assembled in its own tiled layout
+    — a flat ``(A, rows·128)`` concatenate would need a full-bus relayout
+    on TPU before a Pallas kernel could read it."""
     flat = layout.treedef.flatten_up_to(tree)
     assert len(flat) == len(layout.slots)
     A = flat[0].shape[0]
     parts = []
-    cursor = 0  # in elements of the (A, rows·128) flat view
+    cursor = 0  # in bus rows
     order = sorted(range(len(flat)), key=lambda i: layout.slots[i].row)
     for i in order:
         leaf, slot = flat[i], layout.slots[i]
         assert leaf.shape == (A,) + slot.shape, (leaf.shape, A, slot.shape)
-        gap = slot.row * LANE - cursor
+        gap = slot.row - cursor
         assert gap >= 0, (slot.row, cursor)
         if gap:
-            parts.append(jnp.zeros((A, gap), layout.dtype))
+            parts.append(jnp.zeros((A, gap, LANE), layout.dtype))
         seg = leaf.reshape(A, slot.size).astype(layout.dtype)
         pad = slot.rows * LANE - slot.size
         if pad:
             seg = jnp.pad(seg, ((0, 0), (0, pad)))
-        parts.append(seg)
-        cursor = (slot.row + slot.rows) * LANE
-    tail = layout.rows * LANE - cursor
+        parts.append(seg.reshape(A, slot.rows, LANE))
+        cursor = slot.row + slot.rows
+    tail = layout.rows - cursor
     if tail:
-        parts.append(jnp.zeros((A, tail), layout.dtype))
-    return jnp.concatenate(parts, axis=1).reshape(A, layout.rows, LANE)
+        parts.append(jnp.zeros((A, tail, LANE), layout.dtype))
+    return jnp.concatenate(parts, axis=1)
 
 
 def _slot_views(layout: BusLayout, bus: jax.Array):
     """Flat per-slot ``(A, *leaf_shape)`` views of the bus (bus dtype) —
     the single slicing loop behind :func:`unpack_tree` and
-    :func:`leaf_views`."""
+    :func:`leaf_views`.  Each slot's rows are sliced from the tiled bus
+    first (whole 8-row tiles), so no full-bus relayout is needed.  Plain
+    indexing, so a host (numpy) bus unpacks on the host."""
     A, rows, lane = bus.shape
     assert rows == layout.rows and lane == LANE, (bus.shape, layout.rows)
-    flat_view = bus.reshape(A, rows * LANE)
     out = []
     for slot in layout.slots:
-        start = slot.row * LANE
-        seg = jax.lax.slice_in_dim(flat_view, start, start + slot.size,
-                                   axis=1)
-        out.append(seg.reshape((A,) + slot.shape))
+        seg = bus[:, slot.row:slot.row + slot.rows].reshape(
+            A, slot.rows * LANE)
+        out.append(seg[:, :slot.size].reshape((A,) + slot.shape))
     return out
 
 

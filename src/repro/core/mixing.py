@@ -46,8 +46,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
-
 from .topology import Topology
 
 __all__ = ["mix_dense", "mix_shifts", "mix_ppermute", "mix_dense_sharded",
@@ -96,6 +94,19 @@ def _is_masked(topo: Topology) -> bool:
     Duck-typed on the per-agent weight column API so core.mixing never
     imports core.elastic."""
     return hasattr(topo, "term_weights")
+
+
+def _local_roll_shifts(topo: Topology):
+    """Per-term agent shifts ``s`` (term = ``roll(x, s, axis=0)``) when every
+    term of ``topo`` is a cyclic shift of the whole agent axis, else None."""
+    idx = np.arange(topo.n_agents)
+    shifts = []
+    for t in topo.terms:
+        s = (idx - topo.term_sources(t)) % topo.n_agents
+        if np.any(s != s[0]):
+            return None
+        shifts.append(int(s[0]))
+    return tuple(shifts)
 
 
 def _masked_tables(topo: Topology):
@@ -276,7 +287,10 @@ def mix_ppermute(topo: Topology, mesh, agent_axes, tree: Any, *,
 
     With ``use_fused_kernel=True`` the per-term weighted accumulation runs as
     one n-ary Pallas ``gossip_axpy`` combine per leaf instead of a chain of
-    mul/add HBM round-trips (DESIGN §3).
+    mul/add HBM round-trips (DESIGN §3).  When one device holds every
+    agent, each term is a roll of its block, and the combine reads the
+    rolls through its index map (:func:`repro.kernels.ops.gossip_axpy_rolled`)
+    instead of materializing a rolled copy per term.
 
     ``transport`` selects the wire mechanism (DESIGN §6 fallback matrix):
     ``"ppermute"`` forces the shard_map + ``lax.ppermute`` path above;
@@ -353,6 +367,11 @@ def mix_ppermute(topo: Topology, mesh, agent_axes, tree: Any, *,
     weights = tuple(float(t.weight) for t in topo.terms)
     if masked:
         srcs_np, wcols_np = _masked_tables(topo)
+    # every agent on one device: each term is a roll of the local block,
+    # which the fused combine reads through its index map (no rolled copy)
+    roll_shifts = (_local_roll_shifts(topo)
+                   if use_fused_kernel and B == A > 1 and not masked
+                   else None)
 
     def combine(payloads, ws):
         if use_fused_kernel:
@@ -396,6 +415,11 @@ def mix_ppermute(topo: Topology, mesh, agent_axes, tree: Any, *,
             return tuple(
                 combine([permute_term(x, t) for t in topo.terms], ws)
                 for x in leaves)
+        if roll_shifts is not None:
+            from repro.kernels.ops import gossip_axpy_rolled
+            return tuple(gossip_axpy_rolled(x, roll_shifts, weights,
+                                            interpret=interpret)
+                         for x in leaves)
         return tuple(combine([permute_term(x, t) for t in topo.terms],
                              weights)
                      for x in leaves)
@@ -433,12 +457,14 @@ def mix_ppermute(topo: Topology, mesh, agent_axes, tree: Any, *,
     spec = P(axis_flat) if shard_axes is None else P(axis_flat, shard_axes)
     if wire is not None:
         specs = tuple(spec for _ in tree)
-        (out,) = shard_map(body_wire, mesh, specs, (spec,))(*tree)
+        (out,) = jax.shard_map(body_wire, mesh=mesh, in_specs=specs,
+                               out_specs=(spec,), check_vma=False)(*tree)
         return out
 
     flat, treedef = jax.tree_util.tree_flatten(tree)
     specs = tuple(spec for _ in flat)
-    out = shard_map(body, mesh, specs, specs)(*flat)
+    out = jax.shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs,
+                        check_vma=False)(*flat)
     return jax.tree_util.tree_unflatten(treedef, list(out))
 
 
@@ -472,7 +498,8 @@ def mix_dense_sharded(topo: Topology, mesh, agent_axes, shard_axes,
 
     spec = P(axis_flat, shard_axes)
     flat, treedef = jax.tree_util.tree_flatten(tree)
-    out = [shard_map(body, mesh, (spec,), spec)(l) for l in flat]
+    out = [jax.shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                         check_vma=False)(l) for l in flat]
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -699,7 +726,8 @@ def make_overlap_mixer(sched, engine: str = "ppermute", mesh=None,
         out_spec = (P(None, axis_flat) if shard_axes is None
                     else P(None, axis_flat, shard_axes))
         if wire is None:
-            return shard_map(stack_terms, mesh, (in_spec,), out_spec)
+            return jax.shard_map(stack_terms, mesh=mesh, in_specs=(in_spec,),
+                                 out_specs=out_spec, check_vma=False)
 
         # wire-coded issue: stack every payload component per term — the
         # permutes run on the wire dtype, scales ride with their blocks.
@@ -707,7 +735,8 @@ def make_overlap_mixer(sched, engine: str = "ppermute", mesh=None,
             return tuple(stack_terms(l) for l in leaves)
 
         nl = 2 if wire.fmt == "int8" else 1
-        sm = shard_map(body_wire, mesh, (in_spec,) * nl, (out_spec,) * nl)
+        sm = jax.shard_map(body_wire, mesh=mesh, in_specs=(in_spec,) * nl,
+                           out_specs=(out_spec,) * nl, check_vma=False)
         return lambda payload: wire.payload_from_leaves(
             sm(*wire.payload_leaves(payload)))
 
@@ -756,11 +785,14 @@ def make_overlap_mixer(sched, engine: str = "ppermute", mesh=None,
                 else P(None, axis0, shard_axes))
     out0 = P(axis0) if shard_axes is None else P(axis0, shard_axes)
     if wire is None:
-        combine = shard_map(combine_body, mesh, (w_spec, pay_spec), out0)
+        combine = jax.shard_map(combine_body, mesh=mesh,
+                                in_specs=(w_spec, pay_spec), out_specs=out0,
+                                check_vma=False)
     else:
         nl = 2 if wire.fmt == "int8" else 1
-        combine_sm = shard_map(combine_body_wire, mesh,
-                               (w_spec,) + (pay_spec,) * nl, out0)
+        combine_sm = jax.shard_map(combine_body_wire, mesh=mesh,
+                                   in_specs=(w_spec,) + (pay_spec,) * nl,
+                                   out_specs=out0, check_vma=False)
 
         def combine(w, payloads):
             return combine_sm(w, *wire.payload_leaves(payloads))

@@ -22,6 +22,20 @@ Layout: parameters are flattened and tiled to (rows, 128) f32; one grid step
 processes a (BLOCK_ROWS, 128) tile — 8×128-aligned for the VPU, comfortably
 inside the ~16 MB VMEM budget at the default 512×128×4 B×7 buffers ≈ 1.8 MB.
 
+Memory: the EDM kernels write m' over m, ψ' over ψ and φ over g (and the
+EF residual e' over e) through ``input_output_aliases``; the gossip
+combine writes its sum over its first operand.  With the caller donating
+its state, the update then needs no bus-sized buffer beyond its inputs —
+at full width a two-agent bus is ~3.3 GB per buffer, and three fresh
+outputs do not fit one 16 GB chip next to the state.  φ takes g's buffer
+because the train step's g is a temporary (the packed gradients), while
+x may be returned as the new iterate (the overlapped step); an input
+still read after the kernel costs XLA a defensive copy.
+
+Each ``pallas_call`` carries a stable ``name`` (``edm_update``,
+``edm_update_ef_<fmt>``, ``gossip_axpy``, ``gossip_axpy_q8``); it shows in
+the compiled HLO's ``op_name`` and in profiler traces.
+
 Two callers feed these kernels (kernels/ops.py): the per-leaf wrappers
 (``edm_update`` / ``gossip_axpy``) pack each pytree leaf independently —
 one pallas_call and one pad-to-grid per leaf — while the packed parameter
@@ -81,7 +95,7 @@ def _edm_kernel(x_ref, g_ref, m_ref, psi_ref, m_out, psi_out, phi_out, *,
 def edm_update_flat(x, g, m, psi, *, alpha: float, beta: float,
                     block_rows: int = BLOCK_ROWS, interpret: bool = False):
     """All inputs: (rows, 128) f32 with rows % block_rows == 0.
-    Returns (m_new, psi_new, phi)."""
+    Returns (m_new, psi_new, phi), aliased onto (m, psi, g)."""
     rows, lane = x.shape
     assert lane == LANE and rows % block_rows == 0, (x.shape, block_rows)
     grid = (rows // block_rows,)
@@ -89,10 +103,12 @@ def edm_update_flat(x, g, m, psi, *, alpha: float, beta: float,
     out_sds = jax.ShapeDtypeStruct(x.shape, x.dtype)
     return pl.pallas_call(
         functools.partial(_edm_kernel, alpha=alpha, beta=beta),
+        name="edm_update",
         grid=grid,
         in_specs=[spec] * 4,
         out_specs=[spec] * 3,
         out_shape=[out_sds] * 3,
+        input_output_aliases={2: 0, 3: 1, 1: 2},
         interpret=interpret,
     )(x, g, m, psi)
 
@@ -119,7 +135,9 @@ def _edm_ef_int8_kernel(x_ref, g_ref, m_ref, psi_ref, e_ref,
                         m_out, psi_out, q_out, s_out, e_out, *,
                         alpha: float, beta: float):
     # int8 variant: the grid tile IS the scale block (block_rows, 128) — one
-    # symmetric absmax scale per tile, written to a (1, 1) SMEM slot.  Guards
+    # symmetric absmax scale per tile, broadcast over a lane-dense (1, 1,
+    # 128) output block (a (1, 1) block over (n_tiles, 1) is not one Mosaic
+    # can tile).  Guards
     # mirror core/wire.py: non-finite values are masked out of absmax, NaN
     # encodes to 0, ±Inf saturates to ±127; an all-zero tile (the bus pad
     # tail) gets scale 0 and q 0 — no 0/0.
@@ -136,7 +154,7 @@ def _edm_ef_int8_kernel(x_ref, g_ref, m_ref, psi_ref, e_ref,
     m_out[...] = m_new
     psi_out[...] = psi_new
     q_out[...] = q.astype(jnp.int8)
-    s_out[0, 0] = scale
+    s_out[...] = jnp.broadcast_to(scale, s_out.shape)
     e_out[...] = c - q * scale
 
 
@@ -147,9 +165,9 @@ def edm_update_ef_flat(x, g, m, psi, e, *, alpha: float, beta: float,
 
     Returns ``(m', ψ', q, e')`` for ``fmt="bf16"`` and
     ``(m', ψ', q, scale, e')`` for ``fmt="int8"`` with ``scale`` shaped
-    ``(rows // block_rows, 1)`` f32 (one per grid tile, SMEM-written).
-    ``fmt="f32"`` has no quantize to fuse — callers use
-    :func:`edm_update_flat`.
+    ``(rows // block_rows, 1)`` f32 (one per grid tile).  m', ψ' and e' are
+    aliased onto m, ψ and e.  ``fmt="f32"`` has no quantize to fuse —
+    callers use :func:`edm_update_flat`.
     """
     rows, lane = x.shape
     assert lane == LANE and rows % block_rows == 0, (x.shape, block_rows)
@@ -167,21 +185,26 @@ def edm_update_ef_flat(x, g, m, psi, e, *, alpha: float, beta: float,
         if not interpret:
             # int8 VMEM tiles are (32, 128) minimum on TPU.
             assert block_rows % 32 == 0, block_rows
-        s_spec = pl.BlockSpec((1, 1), lambda i: (i, 0),
-                              memory_space=pltpu.SMEM)
+        s_spec = pl.BlockSpec((1, 1, LANE), lambda i: (i, 0, 0))
         out_specs = [spec, spec, spec, s_spec, spec]
         out_shape = [f32, f32,
                      jax.ShapeDtypeStruct(x.shape, jnp.int8),
-                     jax.ShapeDtypeStruct((rows // block_rows, 1),
+                     jax.ShapeDtypeStruct((rows // block_rows, 1, LANE),
                                           jnp.float32), f32]
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         kern,
+        name=f"edm_update_ef_{fmt}",
         grid=grid,
         in_specs=[spec] * 5,
         out_specs=out_specs,
         out_shape=out_shape,
+        input_output_aliases={2: 0, 3: 1, 4: len(out_shape) - 1},
         interpret=interpret,
     )(x, g, m, psi, e)
+    if fmt == "int8":
+        m2, psi2, q, scale, e2 = outs
+        return m2, psi2, q, scale[:, 0, :1], e2
+    return outs
 
 
 def _axpy_kernel(w_ref, *refs):
@@ -198,7 +221,8 @@ def _axpy_kernel(w_ref, *refs):
 
 
 def gossip_axpy_flat(operands, weights, *, block_rows: int | None = None,
-                     interpret: bool = False, out_dtype=None):
+                     interpret: bool = False, out_dtype=None,
+                     tile_shifts=None):
     """Fused n-ary gossip combine  Σₖ wₖ·operandₖ  over (rows, 128) tiles.
 
     ``operands`` are the post-permute neighbor payloads of one gossip step
@@ -211,6 +235,13 @@ def gossip_axpy_flat(operands, weights, *, block_rows: int | None = None,
     from bf16 payloads so the mixed iterate never re-rounds).  The ring
     case of the paper's experiments is the 3-ary instance
     (center/left/right).
+
+    ``tile_shifts`` (static ints, one per operand) read operand k rolled by
+    ``tile_shifts[k]`` whole tiles: output tile i takes tile
+    ``(i - shift) mod n_tiles`` — a roll done by the index map, so the
+    rolled copy never exists in HBM.  Without shifts the sum is written
+    over operand 0 (``input_output_aliases``); with them it is not, since
+    other operands may still read the tiles it would overwrite.
     """
     if block_rows is None:
         block_rows = BLOCK_ROWS
@@ -222,16 +253,27 @@ def gossip_axpy_flat(operands, weights, *, block_rows: int | None = None,
                                                      block_rows)
     assert all(o.shape == operands[0].shape and o.dtype == operands[0].dtype
                for o in operands)
+    n_tiles = rows // block_rows
     spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
+    if tile_shifts is None:
+        in_specs = [spec] * len(operands)
+    else:
+        assert len(tile_shifts) == len(operands), (tile_shifts, operands)
+        in_specs = [pl.BlockSpec((block_rows, LANE),
+                                 lambda i, s=s: ((i - s) % n_tiles, 0))
+                    for s in tile_shifts]
     if out_dtype is None:
         out_dtype = operands[0].dtype
+    aliases = ({1: 0} if tile_shifts is None
+               and jnp.dtype(out_dtype) == operands[0].dtype else {})
     return pl.pallas_call(
         _axpy_kernel,
-        grid=(rows // block_rows,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [spec] * len(operands),
+        name="gossip_axpy",
+        grid=(n_tiles,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + in_specs,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(operands[0].shape, out_dtype),
+        input_output_aliases=aliases,
         interpret=interpret,
     )(w, *operands)
 
@@ -274,6 +316,7 @@ def gossip_axpy_q8_flat(operands, coefs, *, block_rows: int | None = None,
     spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
     return pl.pallas_call(
         _axpy_q8_kernel,
+        name="gossip_axpy_q8",
         grid=(n_tiles,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + [spec] * len(operands),

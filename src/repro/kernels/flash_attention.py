@@ -105,6 +105,7 @@ def flash_attention_kernel_call(q, k, v, *, causal: bool = True,
 
     return pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=o_spec,

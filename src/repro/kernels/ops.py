@@ -1,7 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On a real TPU these run compiled; in this CPU container they execute in
-interpret mode (functionally identical, exercised by the kernel test suite).
+On the TPU backend these compile for Mosaic; on any other backend (the
+CPU test suite) they run in Pallas interpret mode, functionally identical.
+``chip_smoke.py`` checks that a chip run compiled them for Mosaic.
 ``edm_update_tree`` is the pytree-level entry the EDM optimizer uses when
 ``use_fused_kernel=True``.
 """
@@ -21,7 +22,8 @@ from .paged_attention import paged_attention_kernel_call
 from .paged_prefill import paged_prefill_kernel_call
 
 __all__ = ["edm_update", "edm_update_tree", "edm_update_bus",
-           "edm_update_bus_ef", "gossip_axpy", "gossip_axpy_wire",
+           "edm_update_bus_ef", "gossip_axpy", "gossip_axpy_rolled",
+           "gossip_axpy_wire",
            "flash_attention", "paged_attention", "paged_prefill_attention",
            "padded_size"]
 
@@ -196,6 +198,36 @@ def gossip_axpy(operands, weights, *, block_rows: int | None = None,
                             block_rows, interpret)
 
 
+@functools.partial(jax.jit, static_argnames=("shifts", "block_rows",
+                                             "interpret"))
+def _gossip_axpy_rolled_jit(x, weights, shifts, block_rows, interpret):
+    tiles = x[0].size // (block_rows * LANE)
+    out = gossip_axpy_flat([x.reshape(-1, LANE)] * len(shifts), weights,
+                           block_rows=block_rows, interpret=interpret,
+                           tile_shifts=tuple(s * tiles for s in shifts))
+    return out.reshape(x.shape)
+
+
+def gossip_axpy_rolled(x, shifts, weights, *, block_rows: int | None = None,
+                       interpret: bool | None = None):
+    """Σₖ wₖ·roll(x, shiftsₖ, axis=0): the combine of gossip terms that are
+    all cyclic shifts of one device-local agent block ``x`` (A, ...) — the
+    blocked engine with every agent on one device.  Each roll is read
+    through the kernel's index map when an agent's slice is whole
+    ``(block_rows, 128)`` tiles (the packed bus), so no rolled copy of the
+    bus is made; other shapes roll in XLA first."""
+    if block_rows is None:
+        block_rows = BLOCK_ROWS
+    if interpret is None:
+        interpret = not _on_tpu()
+    if x[0].size % (block_rows * LANE):
+        return gossip_axpy([jnp.roll(x, s, axis=0) for s in shifts], weights,
+                           block_rows=block_rows, interpret=interpret)
+    return _gossip_axpy_rolled_jit(x, jnp.asarray(weights, jnp.float32),
+                                   tuple(int(s) % x.shape[0] for s in shifts),
+                                   block_rows, interpret)
+
+
 @functools.partial(jax.jit, static_argnames=("fmt", "block_rows",
                                              "interpret"))
 def _gossip_axpy_wire_jit(payloads, weights, fmt, block_rows, interpret):
@@ -256,7 +288,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 def paged_attention(q, k_pool, v_pool, page_table, kv_len, *,
                     page_size: int, interpret: bool | None = None):
     """Paged decode-attention (DESIGN §10): q (B, K, G, hd) slot-batched
-    single-token queries against (num_pages, page_size, K, hd) page pools,
+    single-token queries against (K, num_pages, page_size, hd) page pools,
     gathered through a (B, n_pages) page table with per-slot ``kv_len``
     masking.  Oracle: :func:`repro.kernels.ref.paged_attention_ref`."""
     if interpret is None:
@@ -275,7 +307,7 @@ def paged_prefill_attention(q, k_chunk, v_chunk, k_pool, v_pool, pt_row,
 
     Model layout in and out: q (1, C, H, hd) chunk queries, k_chunk /
     v_chunk (1, C, K, hd) the in-flight chunk's keys/values (not yet
-    scattered into the pool), pools (num_pages, page_size, K, hd),
+    scattered into the pool), pools (K, num_pages, page_size, hd),
     pt_row (n_pages,) the slot's page-table row.  ``chunk_start`` /
     ``chunk_len`` are traced int32 scalars — NOT part of the jit key, so
     every chunk of every prompt length reuses one compiled kernel.

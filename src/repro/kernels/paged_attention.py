@@ -10,6 +10,11 @@ issued: logical page ``j`` of slot ``b`` reads physical page
 grid walks kv heads and each step processes that head's whole ``G``-query
 group from one gathered page.
 
+The pools are head-major, ``(K, num_pages, page_size, hd)``: one grid step
+fetches the ``(page_size, hd)`` tile of one head of one page, whose two
+minor dims are what Mosaic tiles (a page-major pool would put the kv-head
+axis second-to-last with a block of 1, which Mosaic refuses).
+
 Grid = (slots, kv_heads, pages_per_slot) with the page axis innermost;
 running max / denominator / accumulator live in VMEM scratch exactly as in
 :mod:`repro.kernels.flash_attention`, and the output tile is written on the
@@ -64,8 +69,8 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(block_live)
     def _step():
         q = q_ref[0, 0].astype(jnp.float32)            # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # (page_size, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)            # (page_size, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q * scale, k,
                                 (((1,), (1,)), ((), ())))  # (G, page_size)
         r = ji * page_size + jax.lax.broadcasted_iota(
@@ -92,14 +97,14 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 def paged_attention_kernel_call(q, k_pool, v_pool, page_table, kv_len, *,
                                 page_size: int, interpret: bool = False):
     """q: (B, K, G, hd) — slot-batched single-token queries, grouped by kv
-    head; k_pool, v_pool: (num_pages, page_size, K, hd) page pools;
+    head; k_pool, v_pool: (K, num_pages, page_size, hd) page pools;
     page_table: (B, n_pages) int32 physical-page ids; kv_len: (B,) int32
     valid KV rows per slot (ring mode: ``min(length, window)``).
     Returns (B, K, G, hd)."""
     B, K, G, hd = q.shape
     n_pages = page_table.shape[1]
-    assert k_pool.shape[1] == page_size and k_pool.shape[2] == K, \
-        (k_pool.shape, page_size, K)
+    assert k_pool.shape[0] == K and k_pool.shape[2:] == (page_size, hd), \
+        (k_pool.shape, K, page_size, hd)
     assert page_table.shape[0] == B and kv_len.shape == (B,), \
         (page_table.shape, kv_len.shape, B)
 
@@ -115,10 +120,10 @@ def paged_attention_kernel_call(q, k_pool, v_pool, page_table, kv_len, *,
         in_specs=[
             pl.BlockSpec((1, 1, G, hd),
                          lambda b, k, j, pt, ln: (b, k, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, k, j, pt, ln: (used(pt, ln, b, j), 0, k, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, k, j, pt, ln: (used(pt, ln, b, j), 0, k, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b, k, j, pt, ln: (k, used(pt, ln, b, j), 0, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b, k, j, pt, ln: (k, used(pt, ln, b, j), 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd),
                                lambda b, k, j, pt, ln: (b, k, 0, 0)),
@@ -132,6 +137,7 @@ def paged_attention_kernel_call(q, k_pool, v_pool, page_table, kv_len, *,
                                page_size=page_size, n_pages=n_pages)
     return pl.pallas_call(
         kernel,
+        name="paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,

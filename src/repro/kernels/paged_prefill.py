@@ -90,8 +90,8 @@ def _prefill_kernel(pt_ref, meta_ref, q_ref, kc_ref, vc_ref, kp_ref, vp_ref,
     @pl.when(jnp.logical_and(ji < n_pages, ji * page_size < prev))
     def _page_step():
         q = q_ref[0].astype(jnp.float32)                # (C*G, hd)
-        k = kp_ref[0, :, 0, :].astype(jnp.float32)      # (page_size, hd)
-        v = vp_ref[0, :, 0, :].astype(jnp.float32)
+        k = kp_ref[0, 0].astype(jnp.float32)            # (page_size, hd)
+        v = vp_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q * scale, k,
                                 (((1,), (1,)), ((), ())))  # (C*G, page_size)
         qi = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0) // group
@@ -107,9 +107,18 @@ def _prefill_kernel(pt_ref, meta_ref, q_ref, kc_ref, vc_ref, kp_ref, vp_ref,
         if window:
             mask &= kpos > (start + qi) - window
         # zero never-written value rows: their probs are exactly 0, but
-        # 0·NaN = NaN in the accumulator dot would leak pool poison
-        col_dead = ~jnp.any(mask, axis=0)[:, None]      # (page_size, 1)
-        v = jnp.where(col_dead, 0.0, v)
+        # 0·NaN = NaN in the accumulator dot would leak pool poison.  A
+        # row is live iff some chunk query sees it — query 0 sees the most
+        # under the window — recomputed on the (page_size, hd) grid of v
+        # itself, as Mosaic cannot relayout a reduced mask into a column.
+        rv = ji * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, v.shape, 0)
+        kv_pos = (start - 1) - jnp.mod(start - 1 - rv, window) if window \
+            else rv
+        live = (kv_pos >= 0) & (kv_pos < start) & (rv < prev)
+        if window:
+            live &= kv_pos > start - window
+        v = jnp.where(live, v, 0.0)
         _online(s, mask, v)
 
     @pl.when(ji == n_pages)
@@ -138,7 +147,8 @@ def paged_prefill_kernel_call(q, k_chunk, v_chunk, k_pool, v_pool, pt_row,
     """q: (K, C·G, hd) — chunk queries grouped by kv head, row ``i·G + g``
     is chunk token i, group member g; k_chunk, v_chunk: (K, C, hd) the
     in-flight chunk's keys/values (NOT yet in the pool); k_pool, v_pool:
-    (num_pages, page_size, K, hd) page pools; pt_row: (n_pages,) int32 —
+    (K, num_pages, page_size, hd) head-major page pools (see
+    :mod:`repro.kernels.paged_attention`); pt_row: (n_pages,) int32 —
     ONE slot's page-table row; meta: (2,) int32 ``[chunk_start,
     chunk_len]``.  Returns (K, C·G, hd)."""
     K, CG, hd = q.shape
@@ -146,8 +156,8 @@ def paged_prefill_kernel_call(q, k_chunk, v_chunk, k_pool, v_pool, pt_row,
     assert CG % C == 0, (q.shape, k_chunk.shape)
     G = CG // C
     n_pages = pt_row.shape[0]
-    assert k_pool.shape[1] == page_size and k_pool.shape[2] == K, \
-        (k_pool.shape, page_size, K)
+    assert k_pool.shape[0] == K and k_pool.shape[2:] == (page_size, hd), \
+        (k_pool.shape, K, page_size, hd)
     assert meta.shape == (2,), meta.shape
 
     def used(pt, meta_, j):
@@ -166,10 +176,10 @@ def paged_prefill_kernel_call(q, k_chunk, v_chunk, k_pool, v_pool, pt_row,
             pl.BlockSpec((1, CG, hd), lambda k, j, pt, meta_: (k, 0, 0)),
             pl.BlockSpec((1, C, hd), lambda k, j, pt, meta_: (k, 0, 0)),
             pl.BlockSpec((1, C, hd), lambda k, j, pt, meta_: (k, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda k, j, pt, meta_: (used(pt, meta_, j), 0, k, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda k, j, pt, meta_: (used(pt, meta_, j), 0, k, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda k, j, pt, meta_: (k, used(pt, meta_, j), 0, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda k, j, pt, meta_: (k, used(pt, meta_, j), 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, CG, hd), lambda k, j, pt, meta_: (k, 0, 0)),
         scratch_shapes=[
@@ -183,6 +193,7 @@ def paged_prefill_kernel_call(q, k_chunk, v_chunk, k_pool, v_pool, pt_row,
                                chunk=C, group=G, window=window)
     return pl.pallas_call(
         kernel,
+        name="paged_prefill",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,

@@ -34,14 +34,15 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0):
 
 
 def gather_pages(pool, page_table):
-    """Dense view of a paged pool: (num_pages, page_size, K, hd) gathered
-    through a (B, n_pages) page table → (B, n_pages·page_size, K, hd).
-    Row ``j·page_size + r`` of slot b is row r of physical page
-    ``page_table[b, j]`` — the layout the page allocator maintains."""
+    """Dense view of a head-major paged pool: (K, num_pages, page_size,
+    hd) gathered through a (B, n_pages) page table → (B,
+    n_pages·page_size, K, hd).  Row ``j·page_size + r`` of slot b is row r
+    of physical page ``page_table[b, j]`` — the layout the page allocator
+    maintains."""
     B, n_pages = page_table.shape
-    _, page_size, K, hd = pool.shape
-    dense = jnp.take(pool, page_table.reshape(-1), axis=0)
-    return dense.reshape(B, n_pages * page_size, K, hd)
+    K, _, page_size, hd = pool.shape
+    dense = jnp.take(pool, page_table.reshape(-1), axis=1)
+    return dense.reshape(K, B, n_pages * page_size, hd).transpose(1, 2, 0, 3)
 
 
 def paged_attention_ref(q, k_pool, v_pool, page_table, kv_len, *,
@@ -56,7 +57,7 @@ def paged_attention_ref(q, k_pool, v_pool, page_table, kv_len, *,
     runs of these exact ops (paged gather vs contiguous cache), so it
     asserts EXACT equality (DESIGN §10)."""
     B, K, G, hd = q.shape
-    assert k_pool.shape[1] == page_size, (k_pool.shape, page_size)
+    assert k_pool.shape[2] == page_size, (k_pool.shape, page_size)
     k = gather_pages(k_pool, page_table)
     v = gather_pages(v_pool, page_table)
     out = sdpa_ref(q.reshape(B, 1, K * G, hd), k, v, causal=False,

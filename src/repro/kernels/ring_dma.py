@@ -253,6 +253,7 @@ def ring_combine_shard(x, plan, *, axis_name: str, n_devices: int,
         functools.partial(_ring_kernel, axis_name=axis_name,
                           n_dev=n_devices, n_chunks=n_chunks,
                           chunk_rows=chunk_rows),
+        name="ring_dma_combine",
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pltpu.ANY)],
         out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
@@ -267,7 +268,7 @@ def ring_combine_shard(x, plan, *, axis_name: str, n_devices: int,
             pltpu.SemaphoreType.DMA((2, 2)),               # recv_sem
             pltpu.SemaphoreType.REGULAR((2,)),             # ack_sem per dir
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id, has_side_effects=True),
     )(w, xs)
     return out.reshape(x.shape) if lead else out

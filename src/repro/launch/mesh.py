@@ -14,8 +14,8 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-__all__ = ["make_production_mesh", "make_sim_mesh", "make_gossip_mesh",
-           "gossip_agent_axes", "HW"]
+__all__ = ["make_mesh", "make_production_mesh", "make_sim_mesh",
+           "make_gossip_mesh", "gossip_agent_axes", "HW"]
 
 
 # TPU v5e hardware constants used by the roofline analysis (per chip).
@@ -27,15 +27,23 @@ HW = {
 }
 
 
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the model code shards through
+    ``with_sharding_constraint`` and GSPMD propagation, which Explicit axes
+    (the ``jax.make_mesh`` default) reject."""
+    from jax.sharding import AxisType
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_sim_mesh():
     """1-device mesh with the production axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_gossip_mesh(n_agents: int, pods: int = 1,

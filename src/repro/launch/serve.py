@@ -14,6 +14,10 @@ continuous-batching engine (DESIGN §10).
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3_14b --smoke \
       --continuous-batching --prefill-chunk 8 --max-step-tokens 16 \
       --prompt-dist exact --max-slots 8 --page-size 8 --requests 16
+
+:func:`main` takes an argv list and returns the run's record: the
+engine's metrics and the engine itself (its ``completed`` tokens per
+request) for continuous batching, the generated ids otherwise.
 """
 from __future__ import annotations
 
@@ -25,11 +29,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import greedy_generate
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true")
@@ -67,8 +72,9 @@ def main():
                     help="prompt-length draw: 'bucket' keeps compiles "
                          "bounded for the legacy path, 'exact' is a length "
                          "continuum (chunked path serves it compile-free)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg, decode_window=args.window)
     if args.ckpt:
@@ -108,7 +114,7 @@ def main():
         print(f"generated {metrics['tokens']} tokens over "
               f"{metrics['requests']} requests "
               f"({metrics['tokens_per_s']} tok/s)")
-        return
+        return {"metrics": metrics, "engine": eng}
 
     batch = {"tokens": jax.random.randint(
         jax.random.PRNGKey(1), (args.batch, args.prompt_len), 0,
@@ -128,6 +134,7 @@ def main():
           f"({args.batch * args.new_tokens / dt:.1f} tok/s)")
     for i in range(min(args.batch, 4)):
         print(f"  req{i}: {out[i].tolist()}")
+    return {"generated": out}
 
 
 if __name__ == "__main__":

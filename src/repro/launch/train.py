@@ -1,16 +1,21 @@
 """Training launcher.
 
-On real hardware this runs the decentralized EDM trainer on the production
-mesh; on this CPU container it runs the same program on a 1×1 mesh with the
-agent axis unsharded (reduced configs), which is how the examples and tests
-exercise it.
+Runs the decentralized EDM trainer: on a TPU at published widths (one
+chip holds two blocked agents of smollm_360m — see ``chip_smoke.py``), on
+the CPU with ``--smoke`` reduced configs and interpret-mode kernels, which
+is how the examples and tests exercise it.
 
   PYTHONPATH=src python -m repro.launch.train --arch smollm_360m --smoke \
       --steps 20 --agents 4 --algorithm edm
+
+:func:`main` takes an argv list and returns the run's record (per-step
+losses, compile and step seconds, the compiled step, the final state), so
+scripts drive exactly the CLI's path.
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
@@ -18,13 +23,15 @@ import jax
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.configs.base import RunConfig
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.flags import add_run_flags, run_config_overrides
 from repro.models import build_model
 from repro.train import (build_train_step, bus_layout_for, checkpoint,
-                         init_state, make_gossip_schedule, resolve_features)
+                         init_state, make_gossip_schedule, resolve_features,
+                         state_specs)
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true",
@@ -64,8 +71,9 @@ def main():
                          "may differ from --agents (elastic join/leave): "
                          "surviving agents restore bit-exactly, re-admitted "
                          "agents join at the consensus mean with ψ := x")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
     pod_agents = args.agents == "pod"
@@ -81,7 +89,7 @@ def main():
     run = RunConfig(global_batch=n_agents * args.per_agent_batch,
                     seq_len=args.seq,
                     agents="pod" if pod_agents else "data",
-                    remat=False, **run_config_overrides(args))
+                    **run_config_overrides(args))
     feats = resolve_features(run)
     sched = make_gossip_schedule(run, n_agents,
                                  pods=1 if pod_agents else args.pods,
@@ -129,8 +137,21 @@ def main():
     layout = (bus_layout_for(model, n_agents, shards=shards,
                              groups=feats.groups)
               if feats.packed_bus else None)
-    state = init_state(model, run, n_agents, jax.random.PRNGKey(0),
-                       shards=shards)
+    init = functools.partial(init_state, model, run, n_agents, shards=shards)
+    shardings = None
+    if pod_agents or (mesh is not None and feats.packed_bus):
+        # build the bus state where it lives: agent axis over the mesh's
+        # agent axes, rows FSDP-sharded over 'data' in pod mode
+        # (state_specs, DESIGN §7) — a full-width state for several agents
+        # does not fit one device before it is split
+        from jax.sharding import NamedSharding, PartitionSpec
+        shardings = jax.tree.map(
+            lambda sp: NamedSharding(mesh, sp),
+            state_specs(model, run,
+                        multi_pod=pod_agents or "pod" in mesh.axis_names),
+            is_leaf=lambda x: isinstance(x, PartitionSpec))
+        init = jax.jit(init, out_shardings=shardings)
+    state = init(jax.random.PRNGKey(0))
     if args.resume:
         # elastic join/leave: the checkpoint's agent count may differ from
         # this run's — survivors restore bit-exactly, joiners take the
@@ -138,16 +159,8 @@ def main():
         state = checkpoint.load_state_resized(args.resume, state,
                                               layout=layout)
         print(f"resumed <- {args.resume} @ step {int(state['step'])}")
-    if pod_agents:
-        # place the bus state shard-resident up front: agent axis on 'pod',
-        # rows FSDP-sharded over 'data' (state_specs, DESIGN §7)
-        from jax.sharding import NamedSharding, PartitionSpec
-        from repro.train import state_specs
-        shardings = jax.tree.map(
-            lambda sp: NamedSharding(mesh, sp),
-            state_specs(model, run, multi_pod=True),
-            is_leaf=lambda x: isinstance(x, PartitionSpec))
-        state = jax.tree.map(jax.device_put, state, shardings)
+        if shardings is not None:
+            state = jax.tree.map(jax.device_put, state, shardings)
     # bus-resident state: donate so XLA aliases the superbuffers in place
     # (params/m/psi update without a second HBM copy, DESIGN §5)
     donate = (0,) if feats.packed_bus else ()
@@ -158,20 +171,38 @@ def main():
                                     pods=1 if pod_agents else args.pods),
                    donate_argnums=donate)
     key = jax.random.PRNGKey(1)
-    t0 = time.time()
+    key, kd = jax.random.split(key)
+    batch = sample(kd)
+    # compile ahead of the loop (the jit call below reuses this executable)
+    # so compile time is reported apart from step time
+    t0 = time.perf_counter()
+    compiled = step.lower(state, batch).compile()
+    compile_s = time.perf_counter() - t0
+    print(f"compiled train step in {compile_s:.1f}s", flush=True)
+    losses, consensus, step_s = [], [], []
+    t0 = time.perf_counter()
     for t in range(args.steps):
-        key, kd = jax.random.split(key)
-        state, m = step(state, sample(kd))
+        if t:
+            key, kd = jax.random.split(key)
+            batch = sample(kd)
+        t1 = time.perf_counter()
+        state, m = jax.block_until_ready(step(state, batch))
+        step_s.append(time.perf_counter() - t1)
+        losses.append(float(m["loss"]))
+        consensus.append(float(m["consensus"]))
         if t % 5 == 0 or t == args.steps - 1:
-            print(f"step {t:4d} loss={float(m['loss']):.4f} "
-                  f"consensus={float(m['consensus']):.2e} "
-                  f"({time.time()-t0:.1f}s)", flush=True)
+            print(f"step {t:4d} loss={losses[-1]:.4f} "
+                  f"consensus={consensus[-1]:.2e} "
+                  f"({time.perf_counter()-t0:.1f}s)", flush=True)
     if args.ckpt:
         # full resumable state (params + opt + step + pipeline), stored as
         # logical trees — layout-, sharding- and overlap-mode-independent
         # on disk
         checkpoint.save_state(args.ckpt, state, layout=layout)
         print(f"checkpoint -> {args.ckpt}")
+    return {"losses": losses, "consensus": consensus,
+            "compile_s": compile_s, "step_s": step_s,
+            "compiled": compiled, "state": state}
 
 
 if __name__ == "__main__":

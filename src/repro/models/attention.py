@@ -154,7 +154,7 @@ def paged_prefill_sdpa(q, k_chunk, v_chunk, k_pool, v_pool, pt_row,
     pre-chunk values.
 
     q: (1, C, H, hd); k_chunk, v_chunk: (1, C, K, hd); k_pool, v_pool:
-    (num_pages, page_size, K, hd); pt_row: (n_pages,) physical page ids;
+    (K, num_pages, page_size, hd); pt_row: (n_pages,) physical page ids;
     chunk_start: absolute position of q[0]; chunk_len: valid chunk rows
     (the last chunk is padded — rows ≥ chunk_len are masked everywhere).
 
@@ -282,14 +282,14 @@ def apply_attn(p, cfg, x, positions, *, mode: str = "train",
 
 
 def _gather_pages(pool, page_table):
-    """(num_pages, page_size, K, hd) × (B, n_pages) → dense
+    """(K, num_pages, page_size, hd) × (B, n_pages) → dense
     (B, n_pages·page_size, K, hd) view — the same op sequence as
     :func:`repro.kernels.ref.gather_pages` (kept local: kernels imports
     this module for ``sdpa_ref``)."""
     B, n_pages = page_table.shape
-    _, page_size, K, hd = pool.shape
-    dense = jnp.take(pool, page_table.reshape(-1), axis=0)
-    return dense.reshape(B, n_pages * page_size, K, hd)
+    K, _, page_size, hd = pool.shape
+    dense = jnp.take(pool, page_table.reshape(-1), axis=1)
+    return dense.reshape(K, B, n_pages * page_size, hd).transpose(1, 2, 0, 3)
 
 
 def apply_attn_paged(p, cfg, x, positions, *, pools, page_table, kv_len,
@@ -300,7 +300,7 @@ def apply_attn_paged(p, cfg, x, positions, *, pools, page_table, kv_len,
     x: (B, 1, d) slot-batched new-token activations; positions: (B, 1)
     per-slot absolute position of the new token (ragged — unlike
     :func:`apply_attn`'s uniform decode ``pos``); pools: {"k","v"} page
-    pools ``(num_pages, page_size, K, hd)``; page_table: (B, n_pages)
+    pools ``(K, num_pages, page_size, hd)``; page_table: (B, n_pages)
     physical-page ids; kv_len: (B,) valid KV rows to attend over
     *including* the row written here — the scheduler passes 0 for idle
     slots, whose writes sink to the null page and whose output is junk
@@ -319,7 +319,7 @@ def apply_attn_paged(p, cfg, x, positions, *, pools, page_table, kv_len,
     q, k_new, v_new = _qkv(p, cfg, h, positions)
 
     B = x.shape[0]
-    page_size = pools["k"].shape[1]
+    page_size = pools["k"].shape[2]
     # logical write row: absolute position, folded onto the ring in
     # window mode (same layout as the dense ring cache: row = pos % win)
     row = positions[:, 0] % window if window else positions[:, 0]
@@ -328,8 +328,8 @@ def apply_attn_paged(p, cfg, x, positions, *, pools, page_table, kv_len,
     # idle slots (page-table row all NULL) scatter into the null page —
     # duplicate (0, 0) targets collide only with each other, never with a
     # live slot's pages (allocator invariant).
-    k_pool = pools["k"].at[phys, rin].set(k_new[:, 0])
-    v_pool = pools["v"].at[phys, rin].set(v_new[:, 0])
+    k_pool = pools["k"].at[:, phys, rin].set(k_new[:, 0].transpose(1, 0, 2))
+    v_pool = pools["v"].at[:, phys, rin].set(v_new[:, 0].transpose(1, 0, 2))
 
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     qg = q.reshape(B, K, H // K, hd)
@@ -385,12 +385,12 @@ def apply_attn_paged_prefill(p, cfg, x, *, pools, pt_row, chunk_start,
     # redirect to physical page 0 (the null write sink — same idiom as
     # idle decode slots, see apply_attn_paged).  Ring rows are distinct
     # within one chunk because the engine enforces C <= window.
-    page_size = pools["k"].shape[1]
+    page_size = pools["k"].shape[2]
     row = jnp.mod(qpos, window) if window else qpos
     live = jnp.arange(C) < jnp.asarray(chunk_len, jnp.int32)
     phys = jnp.where(live, pt_row[row // page_size], 0)
     rin = row % page_size
-    k_pool = pools["k"].at[phys, rin].set(k_new[0])
-    v_pool = pools["v"].at[phys, rin].set(v_new[0])
+    k_pool = pools["k"].at[:, phys, rin].set(k_new[0].transpose(1, 0, 2))
+    v_pool = pools["v"].at[:, phys, rin].set(v_new[0].transpose(1, 0, 2))
     y = out.reshape(1, C, cfg.n_heads * cfg.hd) @ p["wo"]
     return resid + y, {"k": k_pool, "v": v_pool}

@@ -12,10 +12,10 @@ surgery — no cache reshapes, no recompilation.
 Layout contract (mirrors the packed-bus alignment idioms of DESIGN §5,
 via :func:`repro.kernels.ops.padded_size`):
 
-* a page holds ``page_size`` token-rows of ``(K, hd)`` each; ``page_size``
-  is a multiple of the 8-row sublane so a ``(page_size, hd)`` page slice
-  is a whole number of 8×128 VPU tiles when ``hd % 128 == 0`` (the
-  full-size configs; smoke shapes run the kernel in interpret mode);
+* pools are head-major: a page holds ``page_size`` token-rows of one kv
+  head in a ``(page_size, hd)`` tile, so the paged kernels fetch one
+  head of one page as a Mosaic-tileable block; ``page_size`` is a
+  multiple of the 8-row sublane;
 * physical page 0 is the **null page**: the allocator never hands it out,
   free slots' page-table rows are all-zero, and idle slots' decode writes
   land there — so a write by a dead slot can never corrupt a live one,
@@ -27,7 +27,7 @@ via :func:`repro.kernels.ops.padded_size`):
   prefill caches scatter into pages without re-indexing.
 
 The pools themselves are device arrays shaped like the model's stacked
-cache tree — ``(n_blocks, num_pages, page_size, K, hd)`` per period
+cache tree — ``(n_blocks, K, num_pages, page_size, hd)`` per period
 position — and flow through the jitted ``serve_step`` unchanged; only the
 allocator below is host-side Python.
 """
@@ -107,7 +107,7 @@ def paged_pool_shapes(cfg: ModelConfig, pcfg: PagedCacheConfig):
             "paged pools cover attention mixers only (SSM/hybrid decode " \
             "keeps O(1) per-slot state — see DESIGN §10 scope note)"
         leaf = jax.ShapeDtypeStruct(
-            (n_blocks, pcfg.num_pages, pcfg.page_size, cfg.n_kv_heads,
+            (n_blocks, cfg.n_kv_heads, pcfg.num_pages, pcfg.page_size,
              cfg.hd), dt)
         shapes.append({"k": leaf, "v": leaf})
     return tuple(shapes)
@@ -127,8 +127,8 @@ def paged_pool_specs(cfg: ModelConfig):
     dense ``lm_cache_specs`` shard)."""
     from jax.sharding import PartitionSpec as P
     period = block_period(cfg)
-    spec = {"k": P(None, None, None, "model", None),
-            "v": P(None, None, None, "model", None)}
+    spec = {"k": P(None, "model", None, None, None),
+            "v": P(None, "model", None, None, None)}
     return tuple(spec for _ in range(period))
 
 

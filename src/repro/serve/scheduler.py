@@ -321,12 +321,12 @@ class ContinuousBatchingEngine:
 
         def one(pool, c):
             n_blocks, _, L, K, hd = c.shape
-            ps = pool.shape[2]
+            ps = pool.shape[3]
             n_used = pages.shape[0]
             rows = jnp.pad(c[:, 0], ((0, 0), (0, n_used * ps - L),
                                      (0, 0), (0, 0)))
             rows = rows.reshape(n_blocks, n_used, ps, K, hd)
-            return pool.at[:, pages].set(rows)
+            return pool.at[:, :, pages].set(rows.transpose(0, 3, 1, 2, 4))
 
         return jax.tree.map(one, pools, caches)
 
@@ -470,6 +470,21 @@ class ContinuousBatchingEngine:
                 self._finish(st)
             else:
                 self.tok[slot, 0] = tok
+
+    def compiled_step_text(self) -> str:
+        """Compiled HLO text of the serving dispatch — the mixed step under
+        chunked prefill, else the decode-only step — lowered at this
+        engine's shapes (after :meth:`run`, the executable already built).
+        A chip check reads which Pallas kernels it holds."""
+        positions, pt, kv = self._decode_inputs()
+        args = (self.params, self.pools, jnp.asarray(self.tok), positions,
+                pt, kv)
+        if self.prefill_chunk is None:
+            return self._decode_step().lower(*args).compile().as_text()
+        chunk = (jnp.zeros((1, self.prefill_chunk), jnp.int32),
+                 jnp.asarray(self.alloc.page_table[0]),
+                 jnp.asarray(0, jnp.int32), jnp.asarray(1, jnp.int32))
+        return self._mixed_step().lower(*args, *chunk).compile().as_text()
 
     # -- arrival loop -------------------------------------------------------
 

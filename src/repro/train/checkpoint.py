@@ -24,6 +24,7 @@ on the layout's total ``rows``, which includes every group's tail pad.
 """
 from __future__ import annotations
 
+import json
 import os
 from typing import Any, Optional, Sequence
 
@@ -34,6 +35,39 @@ __all__ = ["save", "load", "save_state", "load_state", "resize_state",
            "load_state_resized", "export_consensus", "load_consensus"]
 
 _SEP = "|"
+# .npz stores ml_dtypes leaves (bfloat16, float8, ...) as raw void bytes,
+# which load back as dtype ``|V2``; they are written as same-width unsigned
+# ints and this entry records the dtype to view them back as.
+_DTYPES = "__dtypes__"
+
+
+def _savez(path: str, arrays: dict) -> None:
+    exotic = {k: str(a.dtype) for k, a in arrays.items()
+              if a.dtype.kind == "V"}
+    stored = {k: a.view(f"u{a.dtype.itemsize}") if k in exotic else a
+              for k, a in arrays.items()}
+    if exotic:
+        stored[_DTYPES] = np.array(json.dumps(exotic))
+    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+    np.savez(path, **stored)
+
+
+class _Npz:
+    """Read side of :func:`_savez`: ``files`` and ``[key]`` like
+    ``np.load``, with ml_dtypes leaves viewed back to their dtype."""
+
+    def __init__(self, path: str):
+        self._data = np.load(path)
+        self.files = [k for k in self._data.files if k != _DTYPES]
+        # names resolve once ml_dtypes is imported, which jax does
+        self._dtypes = ({k: np.dtype(v) for k, v in
+                         json.loads(str(self._data[_DTYPES])).items()}
+                        if _DTYPES in self._data.files else {})
+
+    def __getitem__(self, key: str) -> np.ndarray:
+        arr = self._data[key]
+        dt = self._dtypes.get(key)
+        return arr if dt is None else arr.view(dt)
 
 
 def _flatten(tree: Any):
@@ -78,18 +112,21 @@ def save(path: str, tree: Any, layout: Optional[Any] = None) -> None:
     are unpacked to the logical tree first, keeping the on-disk format
     layout-independent.
 
-    FSDP-sharded buses (DESIGN §7) serialize like any other state: the
-    bus translation runs where the data lives and the logical tree is
-    pulled to host once — the on-disk format carries no trace of the
+    FSDP-sharded buses (DESIGN §7) serialize like any other state: each
+    bus is gathered to host once and unpacked there — the on-disk format
+    carries no trace of the
     run's sharding or shard-padded layout, so a checkpoint saved sharded
     loads into a gathered run (or a different shard count) and vice
-    versa."""
+    versa.
+
+    The tree is pulled to host before the bus translation: unpacking on
+    the device would hold a second copy of every bus next to the state,
+    which a full-width run on one chip does not have room for."""
+    tree = jax.device_get(tree)
     if layout is not None:
         tree = _unbus(tree, layout)
-    tree = jax.device_get(tree)
     arrays, _ = _flatten(tree)
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    np.savez(path, **arrays)
+    _savez(path, arrays)
 
 
 def load(path: str, like: Any, layout: Optional[Any] = None) -> Any:
@@ -110,7 +147,7 @@ def load(path: str, like: Any, layout: Optional[Any] = None) -> Any:
             else sub,
             like, logical,
             is_leaf=lambda x: _is_bus(x, layout))
-    data = np.load(path)
+    data = _Npz(path)
     keys, refs = _flatten_keys(like)
     leaves = []
     for key, ref in zip(keys, refs):
@@ -159,7 +196,7 @@ def load_state(path: str, like: Any, layout: Optional[Any] = None) -> Any:
     e_like = None
     opt_like = like2.get("opt")
     if isinstance(opt_like, dict) and "e" in opt_like:
-        have = set(np.load(path).files)
+        have = set(_Npz(path).files)
         if not any(k.split(_SEP)[:2] == ["opt", "e"] for k in have):
             opt_like = dict(opt_like)
             e_like = opt_like.pop("e")
@@ -208,7 +245,7 @@ def export_consensus(src_path: str, dst_path: str) -> None:
 
     The reduction runs in float64 and rounds once to the stored dtype, so
     the export is independent of the agent count's summation order."""
-    data = np.load(src_path)
+    data = _Npz(src_path)
     prefix = "params" + _SEP
     out = {}
     for k in data.files:
@@ -218,8 +255,7 @@ def export_consensus(src_path: str, dst_path: str) -> None:
         out[k[len(prefix):]] = (
             leaf.mean(axis=0, dtype=np.float64).astype(leaf.dtype))
     assert out, f"{src_path}: no params leaves to export"
-    os.makedirs(os.path.dirname(dst_path) or ".", exist_ok=True)
-    np.savez(dst_path, **out)
+    _savez(dst_path, out)
 
 
 def load_consensus(path: str, like_params: Any) -> Any:
@@ -314,7 +350,7 @@ def load_state_resized(path: str, like: Any, layout: Optional[Any] = None,
     defaults to the first ``min(A, A′)`` agents; A′ == A with default
     survivors round-trips bit-identically through :func:`load_state`.
     """
-    data = np.load(path)
+    data = _Npz(path)
     pkeys = [k for k in data.files if k.split(_SEP)[0] == "params"]
     assert pkeys, f"{path}: no params leaves in checkpoint"
     a_old = int(data[pkeys[0]].shape[0])

@@ -38,7 +38,7 @@ __all__ = [
     "make_topology", "make_gossip_schedule", "gossip_round_step",
     "prepend_agent_axis", "batch_spec_tree", "Features", "resolve_features",
     "resolve_group_specs", "make_group_plans", "use_packed_bus",
-    "use_overlap", "use_wire", "bus_layout_for",
+    "use_overlap", "use_wire", "bus_layout_for", "shard_local_edm_update",
 ]
 
 
@@ -329,6 +329,36 @@ def _cast_mixer(mix, dtype: Optional[str]):
         lambda tree: mix(jax.tree.map(lambda x: x.astype(dt), tree)))
 
 
+def shard_local_edm_update(mesh, bus_spec, *, alpha: float, beta: float,
+                           block_rows: int, fmt: str = "f32"):
+    """The fused bus EDM update, ``shard_map``-wrapped over ``bus_spec``.
+
+    Mosaic kernels cannot be partitioned by XLA, so wherever a mesh
+    carries the agent axis the kernel runs per shard on its own
+    ``(A_local, rows_local, 128)`` block — griddable by the layout
+    contract.  ``bus_spec`` is ``P(agent_axes)`` (agents="data") or
+    ``P(agent_axes, shard_axes)`` (agents="pod", DESIGN §7).  ``fmt="f32"``
+    gives ``update(x, g, m, psi) -> (m', ψ', φ)``; ``"bf16"``/``"int8"``
+    give the error-feedback variant ``update(x, g, m, psi, e) -> (m', ψ',
+    payload, e')``, whose int8 scales are ``(A, nb)`` blocks sharded like
+    the bus.
+    """
+    from repro.kernels import ops as kops
+
+    if fmt == "f32":
+        body = functools.partial(kops.edm_update_bus, alpha=alpha,
+                                 beta=beta, block_rows=block_rows)
+        in_specs, out_specs = (bus_spec,) * 4, (bus_spec,) * 3
+    else:
+        body = functools.partial(kops.edm_update_bus_ef, alpha=alpha,
+                                 beta=beta, fmt=fmt, block_rows=block_rows)
+        pay_spec = (bus_spec, bus_spec) if fmt == "int8" else bus_spec
+        in_specs = (bus_spec,) * 5
+        out_specs = (bus_spec, bus_spec, pay_spec, bus_spec)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 def build_train_step(model: Model, run: RunConfig, topo,
                      use_fused_kernel: bool = False, mesh=None,
                      agent_axes=None, shard_axes=None,
@@ -369,10 +399,12 @@ def build_train_step(model: Model, run: RunConfig, topo,
 
     With ``shard_axes`` set (``agents="pod"`` + FSDP, DESIGN §7) the bus's
     row axis is sharded over that mesh axis: the gossip permutes, the
-    combine and the fused EDM update all run on each shard's own row block
-    (the fused kernel is shard_map-wrapped so XLA never gathers the bus
-    around an unpartitioned pallas_call), and every bus-shaped
-    intermediate is pinned to the ``P(agent_axes, shard_axes)`` sharding.
+    combine and the fused EDM update all run on each shard's own row block,
+    and every bus-shaped intermediate is pinned to the
+    ``P(agent_axes, shard_axes)`` sharding.  Whenever ``mesh`` carries the
+    agent axis, in either mode, the fused EDM update is shard_map-wrapped
+    (:func:`shard_local_edm_update`): a bare pallas_call cannot be
+    partitioned.
 
     ``straggler_plan`` (a :class:`~repro.core.elastic.StragglerPlan`,
     DESIGN §8) composes with the overlap pipeline only: each step's late
@@ -388,15 +420,17 @@ def build_train_step(model: Model, run: RunConfig, topo,
     kw = dict(use_fused_kernel=use_fused_kernel) if run.algorithm == "edm" else {}
     packed = feats.packed_bus
     shards = 1
-    bus_spec = None
-    if shard_axes is not None:
-        assert packed, "shard_axes composes with the packed bus only"
-        assert mesh is not None and agent_axes is not None, \
-            "shard-resident gossip needs mesh= and agent_axes="
-        shards = int(mesh.shape[shard_axes])
+    bus_spec = update_spec = None
+    if mesh is not None and agent_axes is not None:
         agent_entry = (tuple(agent_axes)
                        if isinstance(agent_axes, (tuple, list)) else agent_axes)
-        bus_spec = P(agent_entry, shard_axes)
+        update_spec = P(agent_entry)
+    if shard_axes is not None:
+        assert packed, "shard_axes composes with the packed bus only"
+        assert update_spec is not None, \
+            "shard-resident gossip needs mesh= and agent_axes="
+        shards = int(mesh.shape[shard_axes])
+        bus_spec = update_spec = P(agent_entry, shard_axes)
     layout = (bus_layout_for(model, sched.n_agents, shards=shards,
                              groups=feats.groups)
               if packed else None)
@@ -418,34 +452,14 @@ def build_train_step(model: Model, run: RunConfig, topo,
 
     fused_update = None
     fused_update_ef = None
-    if packed and shard_axes is not None and use_fused_kernel:
-        # shard-local fused EDM update: one pallas_call per shard over its
-        # own (A_local, rows/S, 128) block — griddable by layout contract.
-        from repro.compat import shard_map as _shard_map
-        from repro.kernels import ops as kops
-
-        def fused_update(x, g, m, psi):
-            body = functools.partial(kops.edm_update_bus, alpha=run.alpha,
-                                     beta=run.beta,
-                                     block_rows=layout.block_rows)
-            return _shard_map(body, mesh, (bus_spec,) * 4,
-                              (bus_spec,) * 3)(x, g, m, psi)
-
+    if packed and update_spec is not None and use_fused_kernel:
+        fused_update = shard_local_edm_update(
+            mesh, update_spec, alpha=run.alpha, beta=run.beta,
+            block_rows=layout.block_rows)
         if codec is not None:
-            # shard-local fused EDM + EF quantize: the payload out-specs
-            # mirror the codec pytree (int8 scales are (A, nb) row-sharded
-            # like the bus — whole scale blocks per shard by layout).
-            pay_spec = ((bus_spec, bus_spec) if codec.fmt == "int8"
-                        else bus_spec)
-
-            def fused_update_ef(x, g, m, psi, e):
-                body = functools.partial(kops.edm_update_bus_ef,
-                                         alpha=run.alpha, beta=run.beta,
-                                         fmt=codec.fmt,
-                                         block_rows=layout.block_rows)
-                return _shard_map(body, mesh, (bus_spec,) * 5,
-                                  (bus_spec, bus_spec, pay_spec,
-                                   bus_spec))(x, g, m, psi, e)
+            fused_update_ef = shard_local_edm_update(
+                mesh, update_spec, alpha=run.alpha, beta=run.beta,
+                block_rows=layout.block_rows, fmt=codec.fmt)
 
     base_mix = None
     if grouped:
@@ -548,11 +562,12 @@ def build_train_step(model: Model, run: RunConfig, topo,
             the per-block reductions never tempt GSPMD into a gather."""
             if bus_spec is None:
                 return encode_ef(codec, c)
-            from repro.compat import shard_map as _shard_map
             pay_spec = ((bus_spec, bus_spec) if codec.fmt == "int8"
                         else bus_spec)
-            return _shard_map(functools.partial(encode_ef, codec), mesh,
-                              (bus_spec,), (pay_spec, bus_spec))(c)
+            return jax.shard_map(functools.partial(encode_ef, codec),
+                                 mesh=mesh, in_specs=(bus_spec,),
+                                 out_specs=(pay_spec, bus_spec),
+                                 check_vma=False)(c)
 
         def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
             pipe = state["pipeline"]

@@ -186,21 +186,21 @@ def test_prefill_kernel_matches_oracle_and_truth(window, start, C, clen):
     H = K * G
     k_hist = rng.standard_normal((start, K, hd)).astype(np.float32)
     v_hist = rng.standard_normal((start, K, hd)).astype(np.float32)
-    k_pool = np.full((num_pages, page_size, K, hd), np.nan, np.float32)
-    v_pool = np.full((num_pages, page_size, K, hd), np.nan, np.float32)
+    k_pool = np.full((K, num_pages, page_size, hd), np.nan, np.float32)
+    v_pool = np.full((K, num_pages, page_size, hd), np.nan, np.float32)
     n_slot_pages = (window // page_size) if window else n_pages
     phys = rng.choice(np.arange(1, num_pages), size=n_slot_pages,
                       replace=False)
     pt_row = np.zeros((n_pages,), np.int32)
     pt_row[:n_slot_pages] = phys
     # null page is a live write sink (clamped reads see weight-0 rows)
-    k_pool[NULL_PAGE] = 0.0
-    v_pool[NULL_PAGE] = 0.0
+    k_pool[:, NULL_PAGE] = 0.0
+    v_pool[:, NULL_PAGE] = 0.0
     for p in range(start):
         row = p % window if window else p
         pg, r = row // page_size, row % page_size
-        k_pool[pt_row[pg], r] = k_hist[p]
-        v_pool[pt_row[pg], r] = v_hist[p]
+        k_pool[:, pt_row[pg], r] = k_hist[p]
+        v_pool[:, pt_row[pg], r] = v_hist[p]
 
     q = rng.standard_normal((1, C, H, hd)).astype(np.float32)
     k_c = rng.standard_normal((1, C, K, hd)).astype(np.float32)

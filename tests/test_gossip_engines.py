@@ -92,6 +92,46 @@ def test_gossip_axpy_arbitrary_shapes(shape, dtype):
                                rtol=tol, atol=tol)
 
 
+# (A, rows, 128) with whole 512-row tiles per agent: rolls via index maps;
+# (A, 3, 17): rolled in XLA, then combined
+@pytest.mark.parametrize("shape", [(4, 1024, 128), (4, 3, 17)],
+                         ids=["tiled", "ragged"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_gossip_axpy_rolled_matches_rolls(shape, dtype):
+    x = jax.random.normal(jax.random.PRNGKey(2), shape).astype(dtype)
+    shifts, weights = (0, 1, -1, 2), (0.4, 0.3, 0.2, 0.1)
+    out = ops.gossip_axpy_rolled(x, shifts, weights, interpret=True)
+    assert out.shape == shape and out.dtype == dtype
+    want = ref.gossip_axpy_ref(
+        [jnp.roll(x, s, axis=0) for s in shifts], weights)
+    tol = 1e-6 if dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("name", ["ring", "exp", "hier"])
+def test_ppermute_all_agents_on_one_device_fused(name):
+    """Blocked engine with every agent on one device: the fused combine
+    reads each term's roll through its index map (hierarchical intra terms
+    are no flat roll and take the materialized path) — both == dense."""
+    from jax.sharding import Mesh
+
+    from repro.core import exp_graph, hierarchical, ring
+    from repro.core.mixing import mix_dense, mix_ppermute
+
+    topo = {"ring": ring(4), "exp": exp_graph(4),
+            "hier": hierarchical(2, 2)}[name]
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    x = jax.random.normal(jax.random.PRNGKey(3), (4, 1024, 128))
+    got = mix_ppermute(topo, mesh, "data", x, use_fused_kernel=True,
+                       interpret=True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(mix_dense(topo, x)),
+                               rtol=1e-5, atol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # mix_ppermute == mix_dense over every shipped topology
 # ---------------------------------------------------------------------------

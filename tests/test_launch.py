@@ -57,6 +57,33 @@ def test_dryrun_existing_artifacts_complete():
     assert n_ok == 80, n_ok
 
 
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "default"])
+def test_compile_cache_dir(tmp_path, from_env):
+    """The entry points' compile cache: ``$JAX_COMPILATION_CACHE_DIR`` when
+    set (entries land there, no other dir is set), else the fixed
+    git-ignored ``<checkout>/.jax_cache``."""
+    code = """
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import enable_compile_cache
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+print("RETURNED=" + enable_compile_cache())
+print("CONFIG=" + str(jax.config.jax_compilation_cache_dir))
+jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0)).block_until_ready()
+"""
+    env = {k: v for k, v in ENV.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    want = os.path.join(REPO, ".jax_cache")
+    if from_env:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    r = _run(["-c", code], env=env)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    assert f"RETURNED={want}\n" in r.stdout and f"CONFIG={want}\n" in r.stdout
+    if from_env:
+        assert os.listdir(want), "no cache entry written"
+    else:
+        with open(os.path.join(REPO, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+
+
 def test_ppermute_engine_multi_device():
     """mix_ppermute == dense-W oracle on an 8-device host mesh, and the HLO
     contains literal collective-permute ops (the paper's gossip primitive)."""
@@ -66,7 +93,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import ring
 from repro.core.mixing import mix_dense, mix_ppermute
-mesh = jax.make_mesh((8,), ("agents",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("agents",))
 topo = ring(8)
 x = {"w": jax.random.normal(jax.random.PRNGKey(0), (8, 4))}
 got = jax.jit(lambda t: mix_ppermute(topo, mesh, "agents", t))(x)

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ModelConfig
+from repro.launch.mesh import make_mesh
 from repro.models.moe import apply_moe, apply_moe_shard_map, init_moe
 
 
@@ -22,7 +23,7 @@ def test_shard_map_moe_matches_plain(E, k, shared):
     p = init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 32))
     ref, aux_ref = apply_moe(p, cfg, x, 1e-6)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     got, aux = jax.jit(
         lambda p, x: apply_moe_shard_map(p, cfg, x, 1e-6, mesh))(p, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -35,7 +36,7 @@ def test_shard_map_moe_grad_finite():
     cfg = _cfg(4, 2, 1)
     p = init_moe(jax.random.PRNGKey(2), cfg)
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 8, 32))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def loss(p):
         y, aux = apply_moe_shard_map(p, cfg, x, 1e-6, mesh)

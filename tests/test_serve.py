@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.serve.engine import greedy_generate, grow_caches, serve_param_specs
 from repro.serve.paged_cache import (NULL_PAGE, PageAllocator,
@@ -165,7 +166,7 @@ def test_paged_never_reads_unallocated_pages():
                 .tolist()) | {NULL_PAGE}
     unallocated = [p for p in range(eng.pcfg.num_pages) if p not in owned]
     poisoned = jax.tree.map(
-        lambda pool: pool.at[:, jnp.asarray(unallocated)].set(jnp.nan),
+        lambda pool: pool.at[:, :, jnp.asarray(unallocated)].set(jnp.nan),
         eng.pools)
     dirty, _ = model.decode_step_paged(
         params, poisoned, jnp.asarray(eng.tok), jnp.asarray(lens), pt,
@@ -186,8 +187,8 @@ def test_paged_kernel_matches_oracle_ragged():
     B, K, G, hd = 4, 2, 3, 16
     page_size, num_pages, n_pages = 8, 12, 3
     q = jnp.asarray(rng.normal(size=(B, K, G, hd)).astype(np.float32))
-    kp = rng.normal(size=(num_pages, page_size, K, hd)).astype(np.float32)
-    vp = rng.normal(size=(num_pages, page_size, K, hd)).astype(np.float32)
+    kp = rng.normal(size=(K, num_pages, page_size, hd)).astype(np.float32)
+    vp = rng.normal(size=(K, num_pages, page_size, hd)).astype(np.float32)
     kv_len = np.array([5, 0, 24, 17], np.int32)     # idle slot 1; full slot 2
     pt = np.zeros((B, n_pages), np.int32)
     used = {0: [1], 2: [2, 3, 4], 3: [5, 6, 7]}
@@ -196,8 +197,8 @@ def test_paged_kernel_matches_oracle_ragged():
     alloc = {p for ps in used.values() for p in ps}
     for p in range(num_pages):
         if p not in alloc:
-            kp[p] = np.nan
-            vp[p] = np.nan
+            kp[:, p] = np.nan
+            vp[:, p] = np.nan
     kp, vp = jnp.asarray(kp), jnp.asarray(vp)
     pt_j, len_j = jnp.asarray(pt), jnp.asarray(kv_len)
     out = paged_attention(q, kp, vp, pt_j, len_j, page_size=page_size)
@@ -338,6 +339,33 @@ def test_consensus_export_is_agent_mean(tmp_path):
     np.testing.assert_array_equal(back["embed"], got["embed"])
 
 
+def test_consensus_export_keeps_bf16_leaves(tmp_path):
+    """bf16 params (every published config's dtype) survive save → export
+    → load: .npz alone would hand them back as raw ``|V2`` bytes."""
+    from repro.train import checkpoint
+
+    bf16 = jnp.dtype("bfloat16")
+    rng = np.random.default_rng(0)
+    params = {"embed": rng.normal(size=(2, 7, 3)).astype(bf16),
+              "norm": rng.normal(size=(2, 3)).astype(np.float32)}
+    src, dst = str(tmp_path / "train.npz"), str(tmp_path / "consensus.npz")
+    checkpoint.save_state(src, {"params": params, "step": np.int32(1)})
+    like = {"params": jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype), params),
+        "step": jax.ShapeDtypeStruct((), np.int32)}
+    back = checkpoint.load_state(src, like)
+    assert back["params"]["embed"].dtype == bf16
+    np.testing.assert_array_equal(back["params"]["embed"], params["embed"])
+    checkpoint.export_consensus(src, dst)
+    cons = checkpoint.load_consensus(dst, {
+        "embed": jax.ShapeDtypeStruct((7, 3), bf16),
+        "norm": jax.ShapeDtypeStruct((3,), np.float32)})
+    assert cons["embed"].dtype == bf16
+    np.testing.assert_array_equal(
+        cons["embed"],
+        params["embed"].mean(axis=0, dtype=np.float64).astype(bf16))
+
+
 def test_consensus_export_from_pod_run_serves(tmp_path):
     """Acceptance: a checkpoint from an ``--agents pod`` (FSDP-sharded)
     training run exports its consensus, loads under ``serve_param_specs``
@@ -381,7 +409,7 @@ def test_consensus_export_from_pod_run_serves(tmp_path):
 
     # load under the serving TP specs and generate
     from jax.sharding import NamedSharding
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     specs = serve_param_specs(model, fsdp=False, multi_pod=False)
     sharded = jax.tree.map(
         lambda x, sp: jax.device_put(x, NamedSharding(mesh, sp)),

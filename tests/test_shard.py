@@ -253,10 +253,14 @@ for overlap in ("off", "delayed"):
     for fused in (False, True):
         run, state, step = build(overlap, fused)
         hlo = step.lower(state, batch).compile().as_text()
-        # bus-shaped permutes: f32[a, r, 128].  The shape pin IS the
-        # no-all-gather guarantee: a gathered operand would be full-rows.
+        # the gossip's own permutes (op_name .../ppermute) are bus-shaped:
+        # f32[a, r, 128].  The shape pin IS the no-all-gather guarantee: a
+        # gathered operand would be full-rows.  (Unpacking the row-sharded
+        # bus into leaves moves small slices across shards with permutes
+        # of its own; those are not gossip.)
         perms = re.findall(
-            r"= f32\\[(\\d+),(\\d+),128\\]\\S* collective-permute\\(", hlo)
+            r"= f32\\[(\\d+),(\\d+),128\\]\\S* collective-permute\\(.*"
+            r"op_name=\\"[^\\"]*/ppermute\\"", hlo)
         assert len(perms) == n_perm, (overlap, fused, perms, n_perm)
         for a, r in perms:
             assert int(r) == layout.shard_rows, \
