@@ -397,6 +397,11 @@ def build_train_step(model: Model, run: RunConfig, topo,
     sits in the backward pass's shadow instead of on the critical path.
     ``overlap="off"`` is bit-identical to the synchronous bus step.
 
+    Both step bodies name their layers with ``jax.named_scope`` —
+    ``bus_unpack``, ``grad``, ``bus_pack``, ``edm_update_bus`` and
+    ``step_metrics`` (DESIGN §13) — which reaches the compiled step only as
+    the ``op_name`` metadata a device trace is read by.
+
     With ``shard_axes`` set (``agents="pod"`` + FSDP, DESIGN §7) the bus's
     row axis is sharded over that mesh axis: the gossip permutes, the
     combine and the fused EDM update all run on each shard's own row block,
@@ -571,7 +576,6 @@ def build_train_step(model: Model, run: RunConfig, topo,
 
         def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
             pipe = state["pipeline"]
-            phi = parambus.pipeline_payload(pipe)
             g_step = state["step"]          # gossip_every == 1 under overlap
             # ISSUE: put the round's permutes of φ(t) on the wire — nothing
             # below until `complete` depends on them.  With a wire codec the
@@ -579,81 +583,96 @@ def build_train_step(model: Model, run: RunConfig, topo,
             # off), so the in-flight bytes are already compressed; the
             # pipeline buffer itself stays f32 (checkpoint/resize shapes are
             # wire-independent).
-            if codec is not None:
-                c = pin_bus(phi + state["opt"]["e"])
-                enc, e_new = encode_pipeline(c)
-                payloads = issue(enc, g_step)
-            else:
-                payloads = issue(phi, g_step)
+            with jax.named_scope("edm_update_bus"):
+                phi = parambus.pipeline_payload(pipe)
+                if codec is not None:
+                    c = pin_bus(phi + state["opt"]["e"])
+                    enc, e_new = encode_pipeline(c)
+                    payloads = issue(enc, g_step)
+                else:
+                    payloads = issue(phi, g_step)
             # COMPUTE: gradients at the pre-mix local iterate φ(t); the
             # whole fwd/bwd is independent of the in-flight permutes.
-            params_tree = parambus.unpack_tree(layout, phi)
-            losses, grads = grad_fn(params_tree, batch)
-            grads = scaled_grads(grads, state["step"])
-            g_bus = pin_bus(parambus.pack_tree(layout, grads))
+            with jax.named_scope("bus_unpack"):
+                params_tree = parambus.unpack_tree(layout, phi)
+            with jax.named_scope("grad"):
+                losses, grads = grad_fn(params_tree, batch)
+                grads = scaled_grads(grads, state["step"])
+            with jax.named_scope("bus_pack"):
+                g_bus = pin_bus(parambus.pack_tree(layout, grads))
             # COMPLETE: weighted combine of the landed payloads (decode
             # folded in when wire-coded), then the bus-resident EDM update
             # on the mixed iterate x(t) = W(t) φ̃(t).  Late slots
             # (straggler_plan) degrade to self-weight (DESIGN §8).
-            late = (straggler_plan.late_at(g_step)
-                    if straggler_plan is not None else None)
-            x_mixed = complete(payloads, g_step, late=late)
-            if codec is not None:
-                sub = {"m": state["opt"]["m"], "psi": state["opt"]["psi"]}
-                phi_new, new_opt = local_opt.step(x_mixed, g_bus, sub)
-                new_opt = {**new_opt, "e": e_new}
-            else:
-                phi_new, new_opt = local_opt.step(x_mixed, g_bus,
-                                                  state["opt"])
-            metrics = {
-                "loss": jnp.mean(losses),
-                "consensus": bus_consensus(x_mixed),
-                "grad_norm": bus_grad_norm(g_bus),
-            }
-            return {"params": x_mixed, "opt": new_opt,
-                    "pipeline": parambus.pipeline_advance(pipe, phi_new),
+            with jax.named_scope("edm_update_bus"):
+                late = (straggler_plan.late_at(g_step)
+                        if straggler_plan is not None else None)
+                x_mixed = complete(payloads, g_step, late=late)
+                if codec is not None:
+                    sub = {"m": state["opt"]["m"], "psi": state["opt"]["psi"]}
+                    phi_new, new_opt = local_opt.step(x_mixed, g_bus, sub)
+                    new_opt = {**new_opt, "e": e_new}
+                else:
+                    phi_new, new_opt = local_opt.step(x_mixed, g_bus,
+                                                      state["opt"])
+                new_pipe = parambus.pipeline_advance(pipe, phi_new)
+            with jax.named_scope("step_metrics"):
+                metrics = {
+                    "loss": jnp.mean(losses),
+                    "consensus": bus_consensus(x_mixed),
+                    "grad_norm": bus_grad_norm(g_bus),
+                }
+            return {"params": x_mixed, "opt": new_opt, "pipeline": new_pipe,
                     "step": state["step"] + 1}, metrics
 
         return train_step
 
     def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
-        params_tree = (parambus.unpack_tree(layout, state["params"])
-                       if packed else state["params"])
-        losses, grads = grad_fn(params_tree, batch)
-        grads = scaled_grads(grads, state["step"])
-        g_step = gossip_round_step(state["step"], run.gossip_every)
-        g_in = pin_bus(parambus.pack_tree(layout, grads)) if packed else grads
-        opt = opt_at(g_step)
-        if run.gossip_every > 1:
-            # local-EDM: amortize gossip over k steps.  lax.cond — not a
-            # dual-evaluation jnp.where — so skip steps execute only the
-            # identity-mixer update and never pay the gossip collectives
-            # (the round clock `g_step` is replicated, so both branches
-            # stay SPMD-consistent).
-            local_opt = opt_at(g_step, mix_override=lambda t: t)
-            do_gossip = (state["step"] % run.gossip_every) == run.gossip_every - 1
-            new_params, new_opt = jax.lax.cond(
-                do_gossip,
-                lambda a: opt.step(*a),
-                lambda a: local_opt.step(*a),
-                (state["params"], g_in, state["opt"]))
-        else:
-            new_params, new_opt = opt.step(state["params"], g_in, state["opt"])
-        if packed:
-            # bus-path metrics: ONE fused reduction over each superbuffer
-            # (pads are zero, so these equal the per-leaf reductions).
-            consensus = bus_consensus(new_params)
-            grad_norm = bus_grad_norm(g_in)
-        else:
-            consensus = consensus_distance(new_params)
-            grad_norm = jnp.sqrt(sum(
-                jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree.leaves(grads)))
-        metrics = {
-            "loss": jnp.mean(losses),
-            "consensus": consensus,
-            "grad_norm": grad_norm,
-        }
+        with jax.named_scope("bus_unpack"):
+            params_tree = (parambus.unpack_tree(layout, state["params"])
+                           if packed else state["params"])
+        with jax.named_scope("grad"):
+            losses, grads = grad_fn(params_tree, batch)
+            grads = scaled_grads(grads, state["step"])
+        with jax.named_scope("bus_pack"):
+            g_in = (pin_bus(parambus.pack_tree(layout, grads)) if packed
+                    else grads)
+        with jax.named_scope("edm_update_bus"):
+            g_step = gossip_round_step(state["step"], run.gossip_every)
+            opt = opt_at(g_step)
+            if run.gossip_every > 1:
+                # local-EDM: amortize gossip over k steps.  lax.cond — not a
+                # dual-evaluation jnp.where — so skip steps execute only the
+                # identity-mixer update and never pay the gossip collectives
+                # (the round clock `g_step` is replicated, so both branches
+                # stay SPMD-consistent).
+                local_opt = opt_at(g_step, mix_override=lambda t: t)
+                do_gossip = ((state["step"] % run.gossip_every)
+                             == run.gossip_every - 1)
+                new_params, new_opt = jax.lax.cond(
+                    do_gossip,
+                    lambda a: opt.step(*a),
+                    lambda a: local_opt.step(*a),
+                    (state["params"], g_in, state["opt"]))
+            else:
+                new_params, new_opt = opt.step(state["params"], g_in,
+                                               state["opt"])
+        with jax.named_scope("step_metrics"):
+            if packed:
+                # bus-path metrics: ONE fused reduction over each superbuffer
+                # (pads are zero, so these equal the per-leaf reductions).
+                consensus = bus_consensus(new_params)
+                grad_norm = bus_grad_norm(g_in)
+            else:
+                consensus = consensus_distance(new_params)
+                grad_norm = jnp.sqrt(sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree.leaves(grads)))
+            metrics = {
+                "loss": jnp.mean(losses),
+                "consensus": consensus,
+                "grad_norm": grad_norm,
+            }
         return {"params": new_params, "opt": new_opt,
                 "step": state["step"] + 1}, metrics
 

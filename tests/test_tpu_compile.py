@@ -16,7 +16,9 @@ for a described chip is written to it but cannot be read back here.
 from __future__ import annotations
 
 import functools
+import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +28,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import edm_update as ek
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from bench import scopes  # noqa: E402
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
 ROWS = 32768                    # a 4M-element bus slice: 64 grid tiles
@@ -220,3 +225,47 @@ def test_fused_bus_update_data_mode_four_chips(topo, mosaic):
                  donate=(0, 1, 2))
     assert _kernels(c) == {"edm_update"}
     assert _bytes(c) < HBM_BYTES        # per device
+
+
+def test_train_step_ops_lie_under_the_scopes(topo, mosaic):
+    """The step the one-chip training cells run (smollm_360m, two agents
+    blocked on a chip, packed bus, fused kernels, seq 256), compiled for a
+    v5e: every op that writes more than a MiB lies under one of the step's
+    scopes, the way the benchmark attributes them (``bench/scopes.py``),
+    and every scope owns some op."""
+    from repro.configs import get_config
+    from repro.configs.base import RunConfig
+    from repro.models import build_model
+    from repro.train import (build_train_step, init_state,
+                             make_gossip_schedule, state_specs)
+    A, S = 2, 256
+    dev = topo.devices[0]
+    mesh = Mesh(np.array([dev]), ("data",))
+    model = build_model(get_config("smollm_360m"))
+    run = RunConfig(global_batch=A, seq_len=S, agents="data",
+                    algorithm="edm", alpha=1e-3, beta=0.9, topology="ring",
+                    gossip_engine="ppermute", packed_bus=True,
+                    agents_per_device=A)
+    shapes = jax.eval_shape(lambda k: init_state(model, run, A, k),
+                            jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda s, sp: _sds(NamedSharding(mesh, sp), s.shape, s.dtype),
+        shapes, state_specs(model, run, multi_pod=False),
+        is_leaf=lambda x: isinstance(x, P))
+    tokens = _sds(SingleDeviceSharding(dev), (A, 1, S), jnp.int32)
+    step = build_train_step(model, run, make_gossip_schedule(run, A),
+                            use_fused_kernel=True, mesh=mesh,
+                            agent_axes="data")
+    text = _compile(step, state, {"tokens": tokens}, donate=(0,)).as_text()
+    comps, _ = scopes.parse_hlo(text)
+    ops = scopes.hlo_ops(text)
+    assert set(ops.values()) - {None} == set(scopes.SCOPES)
+    for c in comps:
+        for name, op in comps[c].items():
+            if name in ops and ops[name] is None and op["kind"] not in (
+                    "parameter", "constant", "tuple", "get-tuple-element",
+                    "bitcast"):
+                sizes = re.findall(r"\[([\d,]*)\]", op["shape"])
+                elems = max(int(np.prod([int(d) for d in s.split(",") if d]))
+                            for s in sizes) if sizes else 0
+                assert elems * 4 <= 1 << 20, op["line"][:200]
