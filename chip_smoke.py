@@ -11,7 +11,8 @@ One chip, through the same entry points as the CLIs:
 1. device — the first device must be a TPU; there is no CPU fallback;
 2. train — ``repro.launch.train`` with two agents blocked on the chip, the
    packed bus, the fused Pallas EDM update and gossip combine on a ring,
-   seq 1024, 5 steps: finite losses, both kernels compiled for Mosaic
+   seq 1024, 5 steps: finite losses, those kernels and the one-pass
+   consensus metric (``bus_consensus``) compiled for Mosaic
    (``tpu_custom_call``), and losses within 1e-3 (relative) of the same
    run on the unfused jnp chain; the checkpoint is exported to consensus;
 3. serve — ``repro.launch.serve`` loads that consensus into the
@@ -104,7 +105,7 @@ def phase_train_one_chip(dev):
               "--per-agent-batch", "1", "--steps", "5"]
     fused = train(common + ["--fused-kernel", "--ckpt", str(ckpt)], "fused")
     found = kernels(fused.pop("compiled").as_text())
-    check({"edm_update", "gossip_axpy"} <= found,
+    check({"edm_update", "gossip_axpy", "bus_consensus"} <= found,
           f"fused step lacks Mosaic kernels: found {sorted(found)}")
     print(f"fused step Mosaic kernels: {sorted(found)}", flush=True)
     del fused["state"]

@@ -34,23 +34,31 @@ def consensus_distance(tree: Any) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# packed-bus diagnostics (DESIGN §5/§6): the bus's pad elements are zero by
-# layout contract, so a single fused reduction over the (A, rows, 128)
-# superbuffer equals the per-leaf reduction over the logical tree — no
-# unpack, no per-leaf reduction kernels on the metrics path.
+# packed-bus diagnostics (DESIGN §5/§13): the bus's pad elements are zero by
+# layout contract, so a reduction over the (A, rows, 128) superbuffer equals
+# the per-leaf reduction over the logical tree — no unpack, no per-leaf
+# reduction kernels on the metrics path.
 # ---------------------------------------------------------------------------
 
 def bus_consensus(bus: jax.Array) -> jax.Array:
-    """‖X − X̄‖²_F over a packed ``(A, rows, 128)`` bus in ONE reduction
-    (pad rows deviate by 0, so this equals the logical-tree consensus)."""
+    """‖X − X̄‖²_F over a packed ``(A, rows, 128)`` bus (pad rows deviate by
+    0, so this equals the logical-tree consensus).
+
+    The XLA expression, for any placement of the agents.  XLA cannot fuse
+    the mean over the agent axis into the reduction that consumes it: on a
+    TPU it makes the mean, broadcasts it back to the bus's shape and reads
+    the bus again, several bus-sized passes in all.  Where one device holds
+    every agent's copy and the fused kernels are on, the train step takes
+    the one-pass Pallas kernel ``repro.kernels.ops.bus_consensus`` instead
+    (``repro.train.trainer.step_consensus``)."""
     dev = bus - jnp.mean(bus, axis=0, keepdims=True)
     return jnp.sum(jnp.square(dev.astype(jnp.float32)))
 
 
 def bus_grad_norm(g_bus: jax.Array) -> jax.Array:
-    """Global gradient norm over a packed gradient bus in ONE reduction
-    (equals the per-leaf sqrt-of-sum over the unpacked grads: the bus is
-    f32 and its pads are zero)."""
+    """Global gradient norm over a packed gradient bus in one reduction, a
+    single read of the bus (equals the per-leaf sqrt-of-sum over the
+    unpacked grads: the bus is f32 and its pads are zero)."""
     return jnp.sqrt(jnp.sum(jnp.square(g_bus.astype(jnp.float32))))
 
 

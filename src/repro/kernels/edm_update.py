@@ -32,9 +32,17 @@ because the train step's g is a temporary (the packed gradients), while
 x may be returned as the new iterate (the overlapped step); an input
 still read after the kernel costs XLA a defensive copy.
 
+``bus_consensus`` reads the whole ``(A, rows, 128)`` bus once and returns
+the consensus distance ‖X − X̄‖²_F as one lane-dense partial per tile: a
+tile holds every agent's copy of the same rows, so the agent mean, the
+deviations and their squares never leave VMEM (XLA cannot fuse the reduce
+over the agent axis into the reduce that consumes it, and makes several
+bus-sized passes of the same expression).
+
 Each ``pallas_call`` carries a stable ``name`` (``edm_update``,
-``edm_update_ef_<fmt>``, ``gossip_axpy``, ``gossip_axpy_q8``); it shows in
-the compiled HLO's ``op_name`` and in profiler traces.
+``edm_update_ef_<fmt>``, ``gossip_axpy``, ``gossip_axpy_q8``,
+``bus_consensus``); it shows in the compiled HLO's ``op_name`` and in
+profiler traces.
 
 Two callers feed these kernels (kernels/ops.py): the per-leaf wrappers
 (``edm_update`` / ``gossip_axpy``) pack each pytree leaf independently —
@@ -54,7 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["edm_update_flat", "edm_update_ef_flat", "gossip_axpy_flat",
-           "gossip_axpy_q8_flat", "BLOCK_ROWS", "LANE"]
+           "gossip_axpy_q8_flat", "bus_consensus_flat", "BLOCK_ROWS", "LANE"]
 
 def _env_block_rows() -> int:
     """Grid-tile height: the knob the real-TPU tuning sweep turns.  Read
@@ -324,3 +332,38 @@ def gossip_axpy_q8_flat(operands, coefs, *, block_rows: int | None = None,
         out_shape=jax.ShapeDtypeStruct(operands[0].shape, jnp.float32),
         interpret=interpret,
     )(coefs, *operands)
+
+
+def _consensus_kernel(x_ref, o_ref):
+    # x_ref: (A, block_rows, 128), every agent's copy of the same rows.
+    # Deviations are taken from agent 0's copy before the mean: the sum of
+    # squares is the same, identical copies read exactly 0 for any A, and
+    # agents near consensus lose no digits to the rounding of the mean.
+    x = x_ref[...]
+    d = x - x[:1]
+    dev = d - jnp.mean(d, axis=0, keepdims=True)
+    sq = jnp.sum(dev * dev, axis=0)                    # (block_rows, 128)
+    o_ref[0] = jnp.sum(sq, axis=0, keepdims=True)     # (1, 128) partial
+
+
+def bus_consensus_flat(bus, *, block_rows: int = BLOCK_ROWS,
+                       interpret: bool = False):
+    """Per-tile partials of ‖X − X̄‖²_F over an ``(A, rows, 128)`` f32 bus
+    with ``rows % block_rows == 0``: one pass, grid ``rows // block_rows``.
+
+    Returns ``(rows // block_rows, 1, 128)`` f32 lane partials (the
+    lane-dense block shape the int8 scales use); their sum is the
+    consensus distance.  Pad rows are zero in every agent and add 0."""
+    A, rows, lane = bus.shape
+    assert lane == LANE and rows % block_rows == 0, (bus.shape, block_rows)
+    assert bus.dtype == jnp.float32, bus.dtype
+    n_tiles = rows // block_rows
+    return pl.pallas_call(
+        _consensus_kernel,
+        name="bus_consensus",
+        grid=(n_tiles,),
+        in_specs=[pl.BlockSpec((A, block_rows, LANE), lambda i: (0, i, 0))],
+        out_specs=pl.BlockSpec((1, 1, LANE), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, 1, LANE), jnp.float32),
+        interpret=interpret,
+    )(bus)

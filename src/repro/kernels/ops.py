@@ -14,16 +14,16 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from .edm_update import (BLOCK_ROWS, LANE, edm_update_flat,
-                         edm_update_ef_flat, gossip_axpy_flat,
-                         gossip_axpy_q8_flat)
+from .edm_update import (BLOCK_ROWS, LANE, bus_consensus_flat,
+                         edm_update_flat, edm_update_ef_flat,
+                         gossip_axpy_flat, gossip_axpy_q8_flat)
 from .flash_attention import flash_attention_kernel_call
 from .paged_attention import paged_attention_kernel_call
 from .paged_prefill import paged_prefill_kernel_call
 
-__all__ = ["edm_update", "edm_update_tree", "edm_update_bus",
-           "edm_update_bus_ef", "gossip_axpy", "gossip_axpy_rolled",
-           "gossip_axpy_wire",
+__all__ = ["bus_consensus", "edm_update", "edm_update_tree",
+           "edm_update_bus", "edm_update_bus_ef", "gossip_axpy",
+           "gossip_axpy_rolled", "gossip_axpy_wire",
            "flash_attention", "paged_attention", "paged_prefill_attention",
            "padded_size"]
 
@@ -108,6 +108,22 @@ def edm_update_bus(x, g, m, psi, *, alpha: float, beta: float,
                                     block_rows=block_rows,
                                     interpret=interpret)
     return (m2.reshape(x.shape), psi2.reshape(x.shape), phi.reshape(x.shape))
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def bus_consensus(bus, *, block_rows: int | None = None,
+                  interpret: bool | None = None):
+    """Consensus distance ‖X − X̄‖²_F of an ``(A, rows, 128)`` f32 bus in
+    ONE ``pallas_call`` that reads the bus once; each tile holds every
+    agent's copy of its rows, so the whole bus must be on the device that
+    runs it.  The bus layout pads ``rows`` to a multiple of ``block_rows``
+    with zeros, which deviate by 0."""
+    if block_rows is None:
+        block_rows = BLOCK_ROWS
+    if interpret is None:
+        interpret = not _on_tpu()
+    return jnp.sum(bus_consensus_flat(bus, block_rows=block_rows,
+                                      interpret=interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "beta", "fmt",

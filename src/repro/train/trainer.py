@@ -39,6 +39,7 @@ __all__ = [
     "prepend_agent_axis", "batch_spec_tree", "Features", "resolve_features",
     "resolve_group_specs", "make_group_plans", "use_packed_bus",
     "use_overlap", "use_wire", "bus_layout_for", "shard_local_edm_update",
+    "step_consensus",
 ]
 
 
@@ -359,6 +360,35 @@ def shard_local_edm_update(mesh, bus_spec, *, alpha: float, beta: float,
                          out_specs=out_specs, check_vma=False)
 
 
+def step_consensus(mesh, agent_axes, shard_axes, *, use_fused_kernel: bool,
+                   block_rows: int) -> Callable:
+    """The packed-bus step's consensus metric ‖X − X̄‖²_F, chosen once for
+    both step bodies.
+
+    The ``bus_consensus`` kernel reads the bus once, but each of its tiles
+    holds every agent's copy of its rows, so it runs only where a device
+    holds the whole bus: with the fused kernels on, and no mesh or a mesh
+    whose agent axes have size 1 with the rows unsharded (``shard_map``-
+    wrapped there, since a bare pallas_call cannot be partitioned).  Agents
+    split across devices (a ring over chips, ``agents="pod"``) and the
+    unfused path keep the XLA expression :func:`bus_consensus`, whose agent
+    mean is a cross-device exchange there anyway.
+    """
+    from repro.kernels import ops as kops
+
+    if not use_fused_kernel or shard_axes is not None:
+        return bus_consensus
+    kernel = functools.partial(kops.bus_consensus, block_rows=block_rows)
+    if mesh is None:
+        return kernel
+    names = ((agent_axes,) if isinstance(agent_axes, str)
+             else tuple(agent_axes or ()))
+    if any(mesh.shape[n] != 1 for n in names):
+        return bus_consensus
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(P(names),),
+                         out_specs=P(), check_vma=False)
+
+
 def build_train_step(model: Model, run: RunConfig, topo,
                      use_fused_kernel: bool = False, mesh=None,
                      agent_axes=None, shard_axes=None,
@@ -465,6 +495,11 @@ def build_train_step(model: Model, run: RunConfig, topo,
             fused_update_ef = shard_local_edm_update(
                 mesh, update_spec, alpha=run.alpha, beta=run.beta,
                 block_rows=layout.block_rows, fmt=codec.fmt)
+
+    consensus_of = (step_consensus(mesh, agent_axes, shard_axes,
+                                   use_fused_kernel=use_fused_kernel,
+                                   block_rows=layout.block_rows)
+                    if packed else None)
 
     base_mix = None
     if grouped:
@@ -619,7 +654,7 @@ def build_train_step(model: Model, run: RunConfig, topo,
             with jax.named_scope("step_metrics"):
                 metrics = {
                     "loss": jnp.mean(losses),
-                    "consensus": bus_consensus(x_mixed),
+                    "consensus": consensus_of(x_mixed),
                     "grad_norm": bus_grad_norm(g_bus),
                 }
             return {"params": x_mixed, "opt": new_opt, "pipeline": new_pipe,
@@ -659,9 +694,10 @@ def build_train_step(model: Model, run: RunConfig, topo,
                                                state["opt"])
         with jax.named_scope("step_metrics"):
             if packed:
-                # bus-path metrics: ONE fused reduction over each superbuffer
-                # (pads are zero, so these equal the per-leaf reductions).
-                consensus = bus_consensus(new_params)
+                # bus-path metrics over each superbuffer (pads are zero, so
+                # these equal the per-leaf reductions): the consensus in one
+                # pass where a device holds every agent (step_consensus).
+                consensus = consensus_of(new_params)
                 grad_norm = bus_grad_norm(g_in)
             else:
                 consensus = consensus_distance(new_params)

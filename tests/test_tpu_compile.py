@@ -227,14 +227,15 @@ def test_fused_bus_update_data_mode_four_chips(topo, mosaic):
     assert _bytes(c) < HBM_BYTES        # per device
 
 
-def test_train_step_ops_lie_under_the_scopes(topo, mosaic):
-    """The step the one-chip training cells run (smollm_360m, two agents
-    blocked on a chip, packed bus, fused kernels, seq 256), compiled for a
-    v5e: every op that writes more than a MiB lies under one of the step's
-    scopes, the way the benchmark attributes them (``bench/scopes.py``),
-    and every scope owns some op."""
+@pytest.fixture(scope="module")
+def one_chip_step_text(topo):
+    """Optimized HLO of the step the one-chip training cells run
+    (smollm_360m, two agents blocked on a chip, packed bus, fused kernels,
+    seq 256), compiled for a v5e, with the ops wrappers emitting Mosaic
+    kernels as they do on a TPU backend."""
     from repro.configs import get_config
     from repro.configs.base import RunConfig
+    from repro.kernels import ops as kops
     from repro.models import build_model
     from repro.train import (build_train_step, init_state,
                              make_gossip_schedule, state_specs)
@@ -253,10 +254,23 @@ def test_train_step_ops_lie_under_the_scopes(topo, mosaic):
         shapes, state_specs(model, run, multi_pod=False),
         is_leaf=lambda x: isinstance(x, P))
     tokens = _sds(SingleDeviceSharding(dev), (A, 1, S), jnp.int32)
-    step = build_train_step(model, run, make_gossip_schedule(run, A),
-                            use_fused_kernel=True, mesh=mesh,
-                            agent_axes="data")
-    text = _compile(step, state, {"tokens": tokens}, donate=(0,)).as_text()
+    on_tpu = kops._on_tpu
+    kops._on_tpu = lambda: True
+    try:
+        step = build_train_step(model, run, make_gossip_schedule(run, A),
+                                use_fused_kernel=True, mesh=mesh,
+                                agent_axes="data")
+        return _compile(step, state, {"tokens": tokens},
+                        donate=(0,)).as_text()
+    finally:
+        kops._on_tpu = on_tpu
+
+
+def test_train_step_ops_lie_under_the_scopes(one_chip_step_text):
+    """Every op of the one-chip training step that writes more than a MiB
+    lies under one of the step's scopes, the way the benchmark attributes
+    them (``bench/scopes.py``), and every scope owns some op."""
+    text = one_chip_step_text
     comps, _ = scopes.parse_hlo(text)
     ops = scopes.hlo_ops(text)
     assert set(ops.values()) - {None} == set(scopes.SCOPES)
@@ -269,3 +283,29 @@ def test_train_step_ops_lie_under_the_scopes(topo, mosaic):
                 elems = max(int(np.prod([int(d) for d in s.split(",") if d]))
                             for s in sizes) if sizes else 0
                 assert elems * 4 <= 1 << 20, op["line"][:200]
+
+
+def test_one_chip_train_step_runs_three_kernels_once(one_chip_step_text):
+    """The one-chip fused step runs exactly three Mosaic kernels, each
+    once a step: the EDM update, the gossip combine, and the consensus
+    metric under ``step_metrics``."""
+    calls = [l for l in one_chip_step_text.splitlines()
+             if "tpu_custom_call" in l and "op_name=" in l]
+    names = [re.search(r'op_name="[^"]*?/(\w+)/pallas_call', l).group(1)
+             for l in calls]
+    assert sorted(names) == ["bus_consensus", "edm_update", "gossip_axpy"]
+    (cons,) = [l for l in calls if "/bus_consensus/" in l]
+    assert "/step_metrics/" in cons
+
+
+def test_bus_consensus_full_width_fits_one_chip(one_chip, mosaic):
+    """The consensus kernel over the two-agent smollm_360m bus: one read
+    of the bus, and no bus-sized temporary (the XLA expression makes the
+    agent mean and its broadcast)."""
+    from repro.kernels import ops
+    bus = _sds(one_chip, (2, _smollm_bus_rows(2), 128))
+    c = _compile(ops.bus_consensus, bus)
+    assert _kernels(c) == {"bus_consensus"}
+    bus_bytes = 2 * _smollm_bus_rows(2) * 128 * 4
+    assert c.memory_analysis().temp_size_in_bytes < (64 << 20)
+    assert _bytes(c) <= bus_bytes + (64 << 20) < HBM_BYTES
