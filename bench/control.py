@@ -17,10 +17,11 @@ Training cells:
   unchanged reads 1 on ``update_norm_gap`` by construction and needs no
   run.
 
-Serving cells: the program serves a ``--window``-second window at the
-cell's own load and the same sample as a run is checked (lower reading);
-at the same served positions, the token a float8 forward pass puts first
-is checked the same way (the control).
+Serving cells: the program serves the cell's own requests at its own
+load, and every finished request is checked, as in a run (lower
+reading); at the same served positions, the token that a float8 forward
+pass over the same tokens puts first is checked the same way (the
+control, in the program's place).
 
 Prints each seed's readings and writes them all to ``--out``.
 """
@@ -70,46 +71,60 @@ def readings_table(cell, seeds, control_seeds, log=print):
     return rows
 
 
-def top_logit(params, m, req, completed) -> float:
-    """The largest reference logit at the served positions of ``req``:
-    served logits are bfloat16, so near-ties within its step there can
-    put a token that is not the reference's best first."""
+def top_logit(params, m, reqs, completed) -> float:
+    """The largest reference logit at the served positions of the longest
+    of ``reqs``: served logits are bfloat16, so near-ties within its step
+    there can put a token that is not the reference's best first."""
     import numpy as np
     from bench.reference import serve as serve_ref
+    req = max(reqs, key=lambda r: len(r.tokens) + len(completed[r.rid]))
     seq, rows = serve_ref._positions(req, np.asarray(completed[req.rid]))
     logits = serve_ref.forward_logits(params, m, seq, rows)[:len(rows)]
     return float(logits.max())
 
 
-def serve_readings(cell, seeds, control_seeds, window, log=print):
+def serve_readings(cell, seeds, control_seeds, log=print):
+    import gc
+    import numpy as np
     from bench.reference import serve as serve_ref
     from bench.weights import seed_key
-    import gc
-    p = cell.p
+
+    def reading(gaps):
+        """The widest gap, and every gap above 0 (the positions whose
+        token is not the reference's best), widest first."""
+        return {"logit_gap": float(np.max(gaps, initial=0.0)),
+                "positions": int(gaps.size),
+                "off_best": sorted((round(float(g), 5)
+                                    for g in gaps[gaps > 0]), reverse=True)}
+
     rows = []
     for seed in seeds:
         t0 = time.perf_counter()
         params = cell.weights(seed_key(seed, 1))
         eng = cell.engine(params)
         cell.warm(eng)
-        reqs = cell.requests(seed, window)
-        res = cell.serve(eng, reqs, deadline=window + p["drain_seconds"])
+        reqs = cell.requests(seed)
+        res = cell.serve(eng, reqs, deadline=max(r.arrival for r in reqs)
+                         + cell.p["drain_seconds"])
         del eng
         gc.collect()
-        sample = serve_ref.sample_requests(seed, reqs, res["completed"],
-                                           int(p["check_requests"]))
-        r = {"seed": seed, "kind": "program", "logit_gap":
-             serve_ref.widest_gap(params, cell.m, sample, res["completed"]),
-             "requests": len(reqs), "served": len(res["completed"]),
-             "top_logit": top_logit(params, cell.m, sample[0],
-                                    res["completed"])}
+        done = [r for r in reqs if r.rid in res["completed"]]
+        t1 = time.perf_counter()
+        r = {"seed": seed, "kind": "program",
+             "unserved": len(reqs) - len(done),
+             **reading(serve_ref.served_gaps(params, cell.m, done,
+                                             res["completed"])),
+             "reference_s": time.perf_counter() - t1,
+             "top_logit": top_logit(params, cell.m, done, res["completed"])}
         log(json.dumps(r) + f"  ({time.perf_counter() - t0:.1f} s)")
         rows.append(r)
         if seed in control_seeds:
-            r = {"seed": seed, "kind": "control_fp8", "logit_gap":
-                 serve_ref.control_gap(params, cell.m, sample,
-                                       res["completed"], "float8_e4m3fn")}
-            log(json.dumps(r))
+            t1 = time.perf_counter()
+            r = {"seed": seed, "kind": "control_fp8", "unserved": 0,
+                 **reading(serve_ref.served_gaps(
+                     params, cell.m, done, res["completed"],
+                     "float8_e4m3fn"))}
+            log(json.dumps(r) + f"  ({time.perf_counter() - t1:.1f} s)")
             rows.append(r)
         del params
         gc.collect()
@@ -122,8 +137,6 @@ def main():
     ap.add_argument("--seeds", type=int, default=12)
     ap.add_argument("--control-seeds", type=int, default=3)
     ap.add_argument("--first-seed", type=int, default=3_000_000_000)
-    ap.add_argument("--window", type=float, default=8.0,
-                    help="serving cells: seconds of arrivals per seed")
     ap.add_argument("--out", required=True)
     args = ap.parse_args()
     import jax
@@ -135,7 +148,7 @@ def main():
     seeds = [args.first_seed + 7919 * i for i in range(args.seeds)]
     controls = set(seeds[:args.control_seeds])
     if spec.kind == "open_loop":
-        rows = serve_readings(cell, seeds, controls, args.window)
+        rows = serve_readings(cell, seeds, controls)
     else:
         rows = readings_table(cell, seeds, controls)
     summary = {}

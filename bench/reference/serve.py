@@ -5,17 +5,15 @@ of one chip fits beside nothing else.
 
 The check on a served request is the gap by which each served token's
 logit lies below the reference's best logit at the same position; it is
-valid for greedy decoding.  The control reads the gap of the token a
-lower-precision forward pass puts first, at every position of the same
-prompts and served tokens: with random weights the best logit leads the
-next by a wide margin at most positions, and a lower precision changes
-the first token at only a few of them.
+valid for greedy decoding.  The control is judged the same way, at the
+same served positions: the token a lower-precision forward pass puts
+first there, in the program's place.
 """
 from __future__ import annotations
 
 import functools
 import json
-from typing import Dict, List, Sequence
+from typing import Dict, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -78,26 +76,10 @@ def forward_logits(params, m: Dict, tokens: np.ndarray, rows: np.ndarray,
 
 
 @jax.jit
-def _widest(ref, pick, n):
-    """The widest gap, over the first ``n`` rows, between the best logit
-    of ``ref`` and that of the token ``pick`` names."""
-    gap = jnp.max(ref, -1) - jnp.take_along_axis(ref, pick[:, None], -1)[:, 0]
-    return jnp.max(jnp.where(jnp.arange(ref.shape[0]) < n, gap, 0.0))
-
-
-def sample_requests(seed: int, reqs: Sequence, completed: Dict, n: int):
-    """The longest finished request and ``n − 1`` others drawn from the
-    seed."""
-    done = [r for r in reqs if r.rid in completed]
-    if not done:
-        return []
-    key = lambda r: len(r.tokens) + len(completed[r.rid])
-    longest = max(done, key=key)
-    others = [r for r in done if r is not longest]
-    rng = np.random.default_rng(int(seed) + 1)
-    pick = rng.choice(len(others), size=min(n - 1, len(others)),
-                      replace=False) if others else []
-    return [longest] + [others[i] for i in sorted(pick)]
+def _gaps(ref, pick):
+    """Per row, the gap between the best logit of ``ref`` and that of the
+    token ``pick`` names."""
+    return jnp.max(ref, -1) - jnp.take_along_axis(ref, pick[:, None], -1)[:, 0]
 
 
 def _positions(req, served):
@@ -107,31 +89,27 @@ def _positions(req, served):
     return seq, np.arange(S - 1, S - 1 + len(served))
 
 
-def widest_gap(params, m: Dict, sample: List, completed: Dict) -> float:
-    """The widest gap, over every served token of the sample, between the
-    reference's best logit and the served token's."""
-    widest = 0.0
-    for r in sample:
+def served_gaps(params, m: Dict, reqs: Sequence, completed: Dict,
+                dtype=None) -> np.ndarray:
+    """The gap at every served position of ``reqs`` between the
+    reference's best logit and the served token's; with ``dtype``, the
+    token that a ``dtype`` forward pass over the same tokens puts first
+    there takes the served token's place (the control)."""
+    out = [np.zeros(0, np.float32)]
+    for r in reqs:
         served = np.asarray(completed[r.rid], np.int32)
         seq, rows = _positions(r, served)
         ref = forward_logits(params, m, seq, rows)
-        pick = jnp.asarray(_padded(served, ROWS_TO))
-        widest = max(widest, float(_widest(ref, pick, len(rows))))
-    return widest
+        if dtype is None:
+            pick = jnp.asarray(_padded(served, ROWS_TO))
+        else:
+            pick = jnp.argmax(forward_logits(params, m, seq, rows, dtype), -1)
+        out.append(np.asarray(_gaps(ref, pick))[:len(rows)])
+        del ref, pick
+    return np.concatenate(out)
 
 
-def control_gap(params, m: Dict, sample: List, completed: Dict,
-                dtype) -> float:
-    """The widest gap of the token a ``dtype`` forward pass puts first, at
-    every position of the same prompts and served tokens, against the
-    float32 reference."""
-    widest = 0.0
-    for r in sample:
-        seq, _ = _positions(r, np.asarray(completed[r.rid], np.int32))
-        rows = np.arange(len(seq))
-        ref = forward_logits(params, m, seq, rows)
-        low = forward_logits(params, m, seq, rows, dtype)
-        widest = max(widest, float(_widest(ref, jnp.argmax(low, -1),
-                                           len(rows))))
-        del ref, low
-    return widest
+def widest_gap(params, m: Dict, reqs: Sequence, completed: Dict) -> float:
+    """The widest of :func:`served_gaps`."""
+    return float(np.max(served_gaps(params, m, reqs, completed),
+                        initial=0.0))

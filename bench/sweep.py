@@ -3,13 +3,15 @@
 the highest rate the engine sustains without a growing backlog.
 
     python3 bench/sweep.py --workload starcoder2_7b.serve.code \
-        --rates 3,4,5,6,7,8 --seconds 30
+        --rates 0.8,1.0,1.2,1.4
 
-One process, one warmed engine, the cell's own mix at each rate.  For each
-rate it prints the requests, the tails of time to first token and of the
-gaps between tokens, the output tokens per second, and the median time to
-first token of the last quarter of the requests over the first quarter's
-(well above 1 means the queue grew all through the window).
+One process, one warmed engine, the cell's own mix at each rate (the
+same requests at every rate, closer together).  For each rate it prints the requests, the tails of
+time to first token and of the gaps between tokens, the output tokens per
+second, and for time to first token and for how late admission ran, the
+median of the last quarter of the requests over the first quarter's.
+Where these climb from one rate to the next, the queue grew all through
+the window; at the lowest rate the schedule alone sets them.
 """
 from __future__ import annotations
 
@@ -32,7 +34,6 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
     ap.add_argument("--rates", required=True)
-    ap.add_argument("--seconds", type=float, default=30.0)
     ap.add_argument("--seed", type=int, default=4_000_000_001)
     ap.add_argument("--set", action="append", default=[],
                     help="key=value: an engine setting of the mix to try "
@@ -51,11 +52,11 @@ def main():
     cell.warm(eng)
     for rate in [float(r) for r in args.rates.split(",")]:
         reqs = drv.make_requests(args.seed, spec.traffic, cell.cfg.vocab_size,
-                                 args.seconds, rate)
-        res = cell.serve(eng, reqs, deadline=args.seconds
+                                 rate)
+        res = cell.serve(eng, reqs, deadline=max(r.arrival for r in reqs)
                          + spec.traffic["drain_seconds"])
         s = drv.summarize(reqs, res)
-        ttft = np.asarray(s["ttft"])
+        ttft, late = np.asarray(s["ttft"]), np.asarray(res["late"])
         q = max(len(ttft) // 4, 1)
         row = {"rate": rate, "settings": args.set, "requests": len(reqs),
                "served": len(res["completed"]),
@@ -65,7 +66,10 @@ def main():
                "itl_p95_ms": 1e3 * drv.pct(s["gaps"], 95),
                "tokens_per_s": s["out_tokens"] / res["wall"],
                "wall_s": res["wall"], "dispatches": res["steps"],
-               "backlog": float(np.median(ttft[-q:]) / np.median(ttft[:q]))}
+               "backlog": float(np.median(ttft[-q:]) / np.median(ttft[:q])),
+               "late_first_ms": 1e3 * float(np.median(late[:q])),
+               "late_last_ms": 1e3 * float(np.median(late[-q:])),
+               "host_pauses_ms": res["host_pauses_ms"]}
         print(json.dumps(row), flush=True)
 
 

@@ -5,9 +5,7 @@ Kernels run in interpret mode there, so nothing here is a speed."""
 from __future__ import annotations
 
 import argparse
-import json
 import pathlib
-import shutil
 import sys
 import time
 
@@ -21,24 +19,12 @@ from bench import harness  # noqa: E402
 TINY_LM = {"n_layers": 2, "d_model": 128, "n_heads": 2, "n_kv_heads": 1,
            "d_ff": 256, "vocab_size": 512}
 TINY_TRAIN = {"seq_len": 32, "trace_steps": 2}
-
-
-def root_with(tmp: pathlib.Path, cell: dict, config_file: str,
-              limits: dict) -> pathlib.Path:
-    """A copy of the benchmark under ``tmp`` with one more cell, its
-    configuration entry and its limits, for a cell ``BENCHMARK.json`` does
-    not list yet."""
-    shutil.copytree(ROOT / "bench", tmp / "bench",
-                    ignore=shutil.ignore_patterns("__pycache__", "testdata"))
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    bench["configs"].append({"name": cell["config"], "source": "x",
-                             "file": config_file, "reduced": [], "why": "x"})
-    bench["workloads"].append(cell)
-    (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
-    shutil.copy(ROOT / config_file, tmp / config_file)
-    (tmp / "bench" / "limits" / f"{cell['name']}.json").write_text(
-        json.dumps({"limits": limits}))
-    return tmp
+TINY_CODER = {"n_layers": 2, "d_model": 128, "n_heads": 2, "n_kv_heads": 1,
+              "head_dim": 64, "d_ff": 256, "vocab_size": 512}
+TINY_CODE = {"rate": 40.0, "requests": 12, "prompt": [40, 0.5, 8, 96],
+             "output": [6, 0.5, 2, 12], "prefill_chunk": 16,
+             "page_size": 16, "max_slots": 8, "max_context": 128,
+             "num_pages": 80, "drain_seconds": 30, "trace_seconds": 0.1}
 
 
 def spec_for(workload: str, model=None, traffic=None,

@@ -233,17 +233,102 @@ def test_same_seed_same_first_steps(tiny_runs):
     assert a["info"]["first_losses"] == b["info"]["first_losses"]
 
 
-def test_every_seed_serves_the_same_schedule():
+SERVE_CELL = "starcoder2_7b.serve.code"
+
+
+def open_loop():
+    return harness.load_module(ROOT / "bench/traffic/open_loop.py")
+
+
+def shape(reqs):
+    return [(r.arrival, len(r.tokens), r.max_new) for r in reqs]
+
+
+@pytest.mark.parametrize("seed", [2**31 + 3, 2**33 + 5, 7])
+def test_every_seed_serves_the_same_fixed_schedule(seed):
     p = json.loads((ROOT / "bench/traffic/serve_code.json").read_text())
-    drv = harness.load_module(ROOT / "bench/traffic/open_loop.py")
-    a, b = (drv.make_requests(seed, p, 49152, 20.0, 2.0)
-            for seed in (2**31 + 3, 2**33 + 5))
-    assert len(a) == len(b) > 20
-    assert [(r.arrival, len(r.tokens), r.max_new) for r in a] == \
-        [(r.arrival, len(r.tokens), r.max_new) for r in b]
-    assert any((r.tokens != s.tokens).any() for r, s in zip(a, b))
-    assert all(p["prompt"][2] <= len(r.tokens) <= p["prompt"][3] for r in a)
-    assert all(p["output"][2] <= r.max_new <= p["output"][3] for r in a)
+    drv = open_loop()
+    want = drv.make_requests(2**31 + 3, p, 49152, p["rate"])
+    got = drv.make_requests(seed, p, 49152, p["rate"])
+    assert shape(got) == shape(want)
+    if seed != 2**31 + 3:
+        assert any((r.tokens != s.tokens).any() for r, s in zip(got, want))
+    assert all(p["prompt"][2] <= len(r.tokens) <= p["prompt"][3]
+               for r in got)
+    assert all(p["output"][2] <= r.max_new <= p["output"][3] for r in got)
+    # the committed mix: 40 arrivals over 37.5 s at 0.95 req/s, 63,797
+    # prompt tokens and 1,950 output tokens, the first 6 due in the traced
+    # 8 s
+    assert len(want) == p["requests"] == 40
+    assert want[-1].arrival == pytest.approx(44.5328 * 0.8 / 0.95, abs=1e-4)
+    assert sum(len(r.tokens) for r in want) == 63_797
+    assert sum(r.max_new for r in want) == 1_950
+    assert sum(r.arrival < p["trace_seconds"] for r in want) == 6
+    # another rate brings the same requests closer together
+    fast = drv.make_requests(seed, p, 49152, 2 * p["rate"])
+    assert [r.arrival * 2 for r in fast] == pytest.approx(
+        [r.arrival for r in want])
+    assert [(len(r.tokens), r.max_new) for r in fast] == \
+        [(len(r.tokens), r.max_new) for r in want]
+
+
+def test_ttft_p75_counts_every_request_and_the_unserved():
+    drv = open_loop()
+    Req = type("Req", (), {})
+    reqs = []
+    for rid, arrival in enumerate([0.0, 1.0, 2.0, 3.0]):
+        r = Req()
+        r.rid, r.arrival = rid, arrival
+        reqs.append(r)
+    # request 3 got no token by the end of the run, at 10 s: it counts
+    # with its wait until then, 7 s
+    res = {"wall": 10.0, "completed": {0: [5, 6, 7], 1: [8, 9], 2: [1, 2]},
+           "stamps": {0: [0.5, 0.6, 0.8], 1: [1.2, 1.5], 2: [4.0, 4.1]}}
+    s = drv.summarize(reqs, res)
+    assert s["ttft"] == pytest.approx([0.5, 0.2, 2.0, 7.0])
+    assert s["gaps"] == pytest.approx([0.1, 0.2, 0.3, 0.1])
+    e2e = drv.end_to_end(s, res["wall"])
+    assert set(e2e) == {"serve_ttft_p75_ms", "serve_itl_p95_ms",
+                        "serve_tokens_per_s"}
+    # linear interpolation between the 3rd and 4th of 0.2, 0.5, 2.0, 7.0
+    assert e2e["serve_ttft_p75_ms"] == pytest.approx(1e3 * (2.0 + 0.25 * 5))
+    assert e2e["serve_itl_p95_ms"] == pytest.approx(1e3 * np.percentile(
+        [0.1, 0.2, 0.3, 0.1], 95))
+    assert e2e["serve_tokens_per_s"] == pytest.approx(7 / 10.0)
+
+
+def test_the_serving_cell_reports_its_own_metrics():
+    spec = harness.Spec(SERVE_CELL)
+    assert [m["name"] for m in spec.e2e] == [
+        "setup_s", "serve_ttft_p75_ms", "serve_itl_p95_ms",
+        "serve_tokens_per_s"]
+    assert {m["name"] for m in spec.per_layer} == {
+        "serve_mfu", "paged_prefill_roofline", "paged_attention_roofline",
+        "device_idle_pct.serve"}
+    assert set(spec.limits["limits"]) == {"logit_gap", "unserved"}
+    train = harness.Spec(TRAIN_CELL)
+    assert not {m["name"] for m in train.e2e + train.per_layer} & {
+        m["name"] for m in spec.e2e + spec.per_layer} - {"setup_s"}
+
+
+@pytest.fixture(scope="module")
+def tiny_serve():
+    return cpu_run.run_cell(SERVE_CELL, 2**31 + 13, model=cpu_run.TINY_CODER,
+                            traffic=cpu_run.TINY_CODE, seconds=0.2)
+
+
+def test_a_tiny_serving_run_serves_every_request(tiny_serve):
+    line, out = tiny_serve
+    assert set(line) == RESULT_KEYS | {"checks"}
+    assert list(line)[-1] == "checks"
+    assert set(line["metrics"]) == {"setup_s", "serve_ttft_p75_ms",
+                                    "serve_itl_p95_ms", "serve_tokens_per_s"}
+    assert line["attempted"] == cpu_run.TINY_CODE["requests"]
+    assert line["failed"] == 0 and line["correct"], line["checks"]
+    info = out["info"]
+    assert info["compiles_in_window"] == 0
+    assert info["gaps"] == info["output_tokens"] - info["requests"]
+    assert {"admit", "engine_step"} <= set(info["host_pauses_ms"])
 
 
 def copy_bench(tmp: pathlib.Path) -> pathlib.Path:
