@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned configs + the paper's own setups.
+"""Architecture registry: the assigned configs + the paper's own setups.
 
 Every entry cites its source model card / paper.  ``get_config(name)`` returns
 the full-size config; ``get_smoke_config(name)`` the reduced same-family
@@ -24,6 +24,7 @@ ARCH_IDS: List[str] = [
     "jamba_1_5_large_398b",
     "deepseek_moe_16b",
     "qwen3_14b",
+    "deepseek_v2_lite",
 ]
 
 _ALIASES = {i.replace("_", "-"): i for i in ARCH_IDS}
@@ -39,6 +40,7 @@ _ALIASES.update({
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
     "deepseek-moe-16b": "deepseek_moe_16b",
     "qwen3-14b": "qwen3_14b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 })
 
 

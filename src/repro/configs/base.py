@@ -10,7 +10,8 @@ import dataclasses
 import math
 from typing import List, Optional, Tuple
 
-__all__ = ["ModelConfig", "RunConfig", "layer_kinds", "reduced"]
+__all__ = ["ModelConfig", "RunConfig", "layer_kinds", "block_period",
+           "period_kinds", "n_blocks", "reduced"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,6 +28,24 @@ class ModelConfig:
     # attention options
     pos_emb: str = "rope"            # rope | sinusoidal (encdec)
     rope_theta: float = 1e4
+    # multi-head latent attention (DeepSeek-V2): attn_kind="mla" projects
+    # keys and values through a normed kv_lora_rank latent; q/k heads are
+    # qk_nope_head_dim + qk_rope_head_dim wide, v heads v_head_dim, and one
+    # rope key of qk_rope_head_dim is shared by every head
+    attn_kind: str = "gqa"           # gqa | mla
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (rope_factor > 1): frequencies between the beta_fast
+    # and beta_slow rotation counts of the original context are ramped from
+    # θ^(-2i/d) down to θ^(-2i/d)/factor; softmax scale × mscale² where
+    # mscale = 0.1·yarn_mscale_all_dim·ln(factor) + 1 (cos/sin unscaled)
+    rope_factor: float = 1.0
+    rope_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     qk_norm: bool = False
     qkv_bias: bool = False
     mlp_gated: bool = True           # SwiGLU vs plain GELU MLP
@@ -38,8 +57,19 @@ class ModelConfig:
     moe_every: int = 1               # layer i uses MoE FFN iff i % moe_every == moe_offset
     moe_offset: int = 0
     dense_d_ff: int = 0              # ffn width of non-MoE layers in mixed models
+    first_k_dense: int = 0           # leading dense layers, before the period scan
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    # expert share (DESIGN §14): the layer holds the n_experts_held experts
+    # with global ids [expert_first, expert_first + n_experts_held) of the
+    # router's n_experts (0 = all), and routes over all of them; dropless
+    # routing computes every assignment to a held expert (no capacity)
+    n_experts_held: int = 0
+    expert_first: int = 0
+    moe_dropless: bool = False
+    norm_topk_prob: bool = True      # renormalise the top-k gate weights
+    routed_scaling_factor: float = 1.0
+    router_aux: str = "switch"       # switch (batch-wise) | seq (sequence-wise)
     # SSM (Mamba-1)
     ssm_state: int = 0
     ssm_conv: int = 4
@@ -63,6 +93,17 @@ class ModelConfig:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
 
     @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of a query/key head (MLA: nope + rope parts)."""
+        if self.attn_kind == "mla":
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.hd
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -77,11 +118,17 @@ class ModelConfig:
     def n_params(self) -> int:
         """Analytic parameter count (embedding + blocks + head)."""
         d, V = self.d_model, self.vocab_size
-        total = V * d * 2  # embed + untied lm head
+        total = V * d * 2 + d  # embed + untied lm head + final norm
         for mixer, ffn in layer_kinds(self):
-            if mixer == "attn" or mixer == "xattn":
+            if mixer == "attn" and self.attn_kind == "mla":
+                H, r = self.n_heads, self.kv_lora_rank
+                total += (d * H * self.qk_head_dim
+                          + d * (r + self.qk_rope_head_dim) + r
+                          + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                          + H * self.v_head_dim * d + d)
+            elif mixer == "attn" or mixer == "xattn":
                 qk = d * self.n_heads * self.hd + d * self.n_kv_heads * self.hd * 2
-                total += qk + self.n_heads * self.hd * d + 2 * d
+                total += qk + self.n_heads * self.hd * d + d
                 if mixer == "xattn":
                     total += qk + self.n_heads * self.hd * d + d
             elif mixer == "ssm":
@@ -93,7 +140,7 @@ class ModelConfig:
                 total += d * ff * (3 if self.mlp_gated else 2) + d
             elif ffn == "moe":
                 e_ff = self.d_ff
-                total += d * self.n_experts + self.n_experts * d * e_ff * 3 + d
+                total += d * self.n_experts + self.experts_held * d * e_ff * 3 + d
                 if self.n_shared_experts:
                     total += d * e_ff * self.n_shared_experts * 3
         if self.family == "encdec":
@@ -112,7 +159,7 @@ class ModelConfig:
         total = self.n_params()
         for mixer, ffn in layer_kinds(self):
             if ffn == "moe":
-                inactive = (self.n_experts - self.experts_per_token) * d * self.d_ff * 3
+                inactive = max(self.experts_held - self.experts_per_token, 0) * d * self.d_ff * 3
                 total -= inactive
         return total
 
@@ -120,10 +167,15 @@ class ModelConfig:
 def layer_kinds(cfg: ModelConfig) -> List[Tuple[str, str]]:
     """Per-layer (mixer, ffn) for the decoder stack.
 
-    mixer ∈ {attn, ssm};  ffn ∈ {dense, moe, none}.
+    mixer ∈ {attn, ssm};  ffn ∈ {dense, moe, none}.  The first
+    ``first_k_dense`` layers are attention + dense FFN; the pattern below
+    holds for the layers after them.
     """
     kinds = []
     for i in range(cfg.n_layers):
+        if i < cfg.first_k_dense:
+            kinds.append(("attn", "dense"))
+            continue
         if cfg.family == "ssm":
             mixer = "ssm"
         elif cfg.family == "hybrid" and cfg.attn_every:
@@ -141,8 +193,9 @@ def layer_kinds(cfg: ModelConfig) -> List[Tuple[str, str]]:
 
 
 def block_period(cfg: ModelConfig) -> int:
-    """Smallest p such that layer kinds repeat with period p and p | n_layers."""
-    kinds = layer_kinds(cfg)
+    """Smallest p such that the kinds of the layers after the leading dense
+    ones repeat with period p, and p divides their count."""
+    kinds = layer_kinds(cfg)[cfg.first_k_dense:]
     n = len(kinds)
     for p in range(1, n + 1):
         if n % p:
@@ -150,6 +203,17 @@ def block_period(cfg: ModelConfig) -> int:
         if all(kinds[i] == kinds[i % p] for i in range(n)):
             return p
     return n
+
+
+def period_kinds(cfg: ModelConfig) -> List[Tuple[str, str]]:
+    """The (mixer, ffn) of each position of the scanned period block."""
+    k = cfg.first_k_dense
+    return layer_kinds(cfg)[k:k + block_period(cfg)]
+
+
+def n_blocks(cfg: ModelConfig) -> int:
+    """How many period blocks the scan runs."""
+    return (cfg.n_layers - cfg.first_k_dense) // block_period(cfg)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,7 +291,7 @@ def reduced(cfg: ModelConfig, n_layers: int = 2, d_model: int = 256,
     """Reduced same-family variant for CPU smoke tests (≤4 experts etc.)."""
     period = block_period(cfg)
     n_layers = max(n_layers, period)
-    n_layers = (n_layers + period - 1) // period * period
+    n_layers = (n_layers + period - 1) // period * period + cfg.first_k_dense
     n_heads = min(cfg.n_heads, 4) if cfg.n_heads else 0
     n_kv = min(cfg.n_kv_heads, n_heads) if cfg.n_kv_heads else 0
     if n_kv and cfg.n_kv_heads == cfg.n_heads:
@@ -243,6 +307,8 @@ def reduced(cfg: ModelConfig, n_layers: int = 2, d_model: int = 256,
         dense_d_ff=min(cfg.dense_d_ff, 2 * d_model) if cfg.dense_d_ff else 0,
         vocab_size=min(cfg.vocab_size, vocab),
         n_experts=min(cfg.n_experts, 4),
+        n_experts_held=min(cfg.n_experts_held, 2),
+        expert_first=min(cfg.expert_first, 2),
         experts_per_token=min(cfg.experts_per_token, 2),
         # dropless at smoke scale (C ≥ T·k/E · E/k): prefill↔decode must agree
         capacity_factor=8.0,
@@ -255,6 +321,11 @@ def reduced(cfg: ModelConfig, n_layers: int = 2, d_model: int = 256,
         attn_offset=min(cfg.attn_offset, min(cfg.attn_every, n_layers) - 1)
         if cfg.attn_every else 0,
         sliding_window=min(cfg.sliding_window, 64) if cfg.sliding_window else 0,
+        kv_lora_rank=min(cfg.kv_lora_rank, 64),
+        qk_nope_head_dim=min(cfg.qk_nope_head_dim, 32),
+        qk_rope_head_dim=min(cfg.qk_rope_head_dim, 16),
+        v_head_dim=min(cfg.v_head_dim, 32),
+        rope_original_max_pos=min(cfg.rope_original_max_pos, 64),
         dtype="float32",
     )
     return dataclasses.replace(cfg, **updates)

@@ -24,7 +24,8 @@ __all__ = ["Model", "build_model", "input_specs", "batch_specs"]
 class Model:
     cfg: ModelConfig
     init: Callable                    # key -> params
-    loss: Callable                    # (params, batch) -> scalar
+    loss: Callable                    # (params, batch) -> scalar; decoder
+                                      # LMs: with_stats=True -> (scalar, counters)
     prefill: Callable                 # (params, batch) -> (logits, caches)
     decode_step: Callable             # (params, caches, token, pos) -> (logits, caches)
     init_cache: Callable              # (batch, length) -> caches
@@ -77,9 +78,10 @@ def build_model(cfg: ModelConfig, decode_window: int = 0,
 
     nf = _frontend_tokens(cfg)
 
-    def loss(params, batch, remat=True, remat_policy="full"):
+    def loss(params, batch, remat=True, remat_policy="full",
+             with_stats=False):
         return tf.lm_loss(cfg, params, batch, remat=remat, unroll=unroll,
-                          remat_policy=remat_policy)
+                          remat_policy=remat_policy, with_stats=with_stats)
 
     def prefill(params, batch):
         return tf.lm_prefill(cfg, params, batch["tokens"],

@@ -1,4 +1,6 @@
-"""GQA attention: full-causal, sliding-window, cross, and cached decode.
+"""GQA attention: full-causal, sliding-window, cross, and cached decode;
+multi-head latent attention (MLA, DeepSeek-V2) with YaRN rope on the
+training path and through a dense cache.
 
 The jnp path here is the reference used for training/dry-run lowering; the
 Pallas flash kernel (repro.kernels.flash_attention) is the TPU hot path and is
@@ -8,12 +10,16 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .layers import dense_init, rms_norm, rope
 
-__all__ = ["init_attn", "apply_attn", "apply_attn_paged",
+__all__ = ["init_attn", "apply_attn", "apply_attn_paged", "apply_mla",
+           "init_mla", "mla_softmax_scale", "yarn_inv_freq",
            "apply_attn_paged_prefill", "init_kv_cache", "sdpa_ref",
            "sdpa_pos_ref", "prev_page_positions", "paged_prefill_sdpa"]
 
@@ -31,6 +37,8 @@ def set_bf16_path(flag: bool) -> None:
 
 
 def init_attn(key, cfg, cross: bool = False) -> Dict:
+    if cfg.attn_kind == "mla":
+        return init_mla(key, cfg)
     d, H, K, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
     ks = jax.random.split(key, 5)
     dt = jnp.dtype(cfg.dtype)
@@ -52,10 +60,12 @@ def init_attn(key, cfg, cross: bool = False) -> Dict:
 
 
 def sdpa_ref(q, k, v, *, causal: bool, window: int = 0,
-             q_offset: int = 0, kv_len: Optional[jax.Array] = None):
+             q_offset: int = 0, kv_len: Optional[jax.Array] = None,
+             scale: Optional[float] = None):
     """Scaled dot-product attention with GQA head sharing.
 
-    q: (B, Sq, H, hd); k, v: (B, Sk, K, hd).  H % K == 0.
+    q, k: (B, Sq|Sk, H|K, hd); v: (B, Sk, K, hd_v).  H % K == 0.
+    ``scale`` defaults to hd^-1/2.
     ``q_offset``: absolute position of q[0] (for cached decode).
     ``kv_len``:   optional dynamic number of valid kv entries (decode);
                   a scalar, or a ``(B,)`` array for ragged slot batches
@@ -64,7 +74,7 @@ def sdpa_ref(q, k, v, *, causal: bool, window: int = 0,
     B, Sq, H, hd = q.shape
     K = k.shape[2]
     G = H // K
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     bf16_path = _BF16_PATH["on"] and q.dtype == jnp.bfloat16
     acc_dt = q.dtype if bf16_path else jnp.float32
     qf = q.astype(acc_dt).reshape(B, Sq, K, G, hd)
@@ -92,7 +102,7 @@ def sdpa_ref(q, k, v, *, causal: bool, window: int = 0,
         logits = jnp.where(mask[None, None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(acc_dt)
     out = jnp.einsum("bkgqs,bskh->bqkgh", probs, vf)
-    return out.reshape(B, Sq, H, hd).astype(q.dtype)
+    return out.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 def sdpa_pos_ref(q, k, v, *, q_pos, k_pos, k_valid, window: int = 0):
@@ -204,12 +214,115 @@ def _qkv(p, cfg, x, positions):
 
 
 def init_kv_cache(cfg, batch: int, length: int, dtype=None):
-    K, hd = cfg.n_kv_heads, cfg.hd
     dt = dtype or jnp.dtype(cfg.dtype)
+    if cfg.attn_kind == "mla":
+        # the expanded per-head keys and values, not the latent
+        H = cfg.n_heads
+        return {"k": jnp.zeros((batch, length, H, cfg.qk_head_dim), dt),
+                "v": jnp.zeros((batch, length, H, cfg.v_head_dim), dt)}
+    K, hd = cfg.n_kv_heads, cfg.hd
     return {
         "k": jnp.zeros((batch, length, K, hd), dt),
         "v": jnp.zeros((batch, length, K, hd), dt),
     }
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1)
+# ---------------------------------------------------------------------------
+
+def yarn_inv_freq(cfg) -> np.ndarray:
+    """(qk_rope_head_dim / 2,) rope frequencies.  With ``rope_factor`` > 1,
+    YaRN: dimension i keeps θ^(-2i/d) below the beta_fast correction
+    dimension, takes θ^(-2i/d)/factor above the beta_slow one, and a linear
+    ramp between them."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    freq = base ** -(np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if cfg.rope_factor <= 1:
+        return freq.astype(np.float32)
+
+    def corr_dim(n_rot):
+        return (dim * math.log(cfg.rope_original_max_pos
+                               / (n_rot * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(corr_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.yarn_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (freq * (1 - ramp) + freq / cfg.rope_factor * ramp).astype(
+        np.float32)
+
+
+def mla_softmax_scale(cfg) -> float:
+    """qk_head_dim^-1/2, times YaRN's mscale² where rope is scaled."""
+    m = (0.1 * cfg.yarn_mscale_all_dim * math.log(cfg.rope_factor) + 1.0
+         if cfg.rope_factor > 1 else 1.0)
+    return cfg.qk_head_dim ** -0.5 * m * m
+
+
+def init_mla(key, cfg) -> Dict:
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    ks = jax.random.split(key, 4)
+    dt = jnp.dtype(cfg.dtype)
+    return {
+        "ln": jnp.zeros((d,), dt),
+        "wq": dense_init(ks[0], (d, H * cfg.qk_head_dim), 0, dt),
+        "wkv_a": dense_init(ks[1], (d, r + cfg.qk_rope_head_dim), 0, dt),
+        "latent": {"ln": jnp.zeros((r,), dt)},
+        "wkv_b": dense_init(ks[2], (r, H * (cfg.qk_nope_head_dim
+                                            + cfg.v_head_dim)), 0, dt),
+        "wo": dense_init(ks[3], (H * cfg.v_head_dim, d), 0, dt),
+    }
+
+
+def _mla_qkv(p, cfg, h, positions):
+    """q, k (B, S, H, nope + rope) and v (B, S, H, v_head_dim) of normed
+    hidden states h: keys and values from the normed latent, one rope key
+    shared by every head."""
+    B, S, _ = h.shape
+    H, dn, dr, r = (cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                    cfg.kv_lora_rank)
+    inv_freq = jnp.asarray(yarn_inv_freq(cfg))
+    q = (h @ p["wq"]).reshape(B, S, H, dn + dr)
+    kv_a = h @ p["wkv_a"]
+    latent = rms_norm(kv_a[..., :r], p["latent"]["ln"], cfg.norm_eps)
+    kv = (latent @ p["wkv_b"]).reshape(B, S, H, dn + cfg.v_head_dim)
+    q_pe = rope(q[..., dn:], positions, cfg.rope_theta, inv_freq)
+    k_pe = rope(kv_a[..., None, r:], positions, cfg.rope_theta, inv_freq)
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :dn],
+                         jnp.broadcast_to(k_pe, (B, S, H, dr))], axis=-1)
+    return q, k, kv[..., dn:]
+
+
+def apply_mla(p, cfg, x, positions, *, mode: str = "train",
+              cache: Optional[Dict] = None) -> Tuple:
+    """MLA sub-block with pre-norm + residual, in the ``jax.named_scope``
+    ``mla``.  ``train``/``prefill`` attend causally over the sequence
+    (prefill also returns the per-head keys and values as the cache);
+    ``decode`` writes one token into that dense cache and attends over it.
+    Returns (y, new_cache)."""
+    with jax.named_scope("mla"):
+        h = rms_norm(x, p["ln"], cfg.norm_eps)
+        B, S = h.shape[:2]
+        scale = mla_softmax_scale(cfg)
+        q, k, v = _mla_qkv(p, cfg, h, positions)
+        new_cache = None
+        if mode in ("train", "prefill"):
+            out = sdpa_ref(q, k, v, causal=True, scale=scale)
+            if mode == "prefill":
+                new_cache = {"k": k, "v": v}
+        else:
+            assert mode == "decode" and cache is not None, mode
+            pos = positions[0, 0]
+            k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, pos, 1)
+            v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, pos, 1)
+            out = sdpa_ref(q, k, v, causal=False, kv_len=pos + 1,
+                           scale=scale)
+            new_cache = {"k": k, "v": v}
+        y = out.reshape(B, S, cfg.n_heads * cfg.v_head_dim) @ p["wo"]
+        return x + y, new_cache
 
 
 def apply_attn(p, cfg, x, positions, *, mode: str = "train",
@@ -225,6 +338,10 @@ def apply_attn(p, cfg, x, positions, *, mode: str = "train",
       "cross"   — encoder-decoder cross attention (xattn_kv = (k, v)).
     Returns (y, new_cache).
     """
+    if cfg.attn_kind == "mla":
+        assert not (window or cfg.sliding_window) and mode != "cross", \
+            "MLA runs full causal self-attention only"
+        return apply_mla(p, cfg, x, positions, mode=mode, cache=cache)
     resid = x
     h = rms_norm(x, p["ln"], cfg.norm_eps)
     win = window or cfg.sliding_window

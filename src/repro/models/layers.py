@@ -27,11 +27,15 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (x * (1.0 + weight.astype(jnp.float32))).astype(dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding.  x: (..., S, H, hd); positions: (..., S)."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq=None) -> jax.Array:
+    """Rotary embedding over the two halves of each head.  x: (..., S, H,
+    hd); positions: (..., S); ``inv_freq`` (hd/2,) replaces θ's own
+    frequencies (YaRN)."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = (1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+             if inv_freq is None else inv_freq)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, half)
     cos = jnp.cos(angles)[..., :, None, :]   # (..., S, 1, half)
     sin = jnp.sin(angles)[..., :, None, :]

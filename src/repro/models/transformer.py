@@ -4,7 +4,8 @@ Layers are grouped into **period blocks** (configs.base.block_period): all
 layers at the same position-within-period share a stacked parameter tree of
 leading dim ``n_blocks`` and the stack is driven by ``jax.lax.scan`` — this
 keeps the HLO size O(period) instead of O(n_layers), which is what makes the
-94-layer MoE dry-run compile in seconds.
+94-layer MoE dry-run compile in seconds.  A model's ``first_k_dense``
+leading layers (``params["lead"]``, one tree each) run before the scan.
 """
 from __future__ import annotations
 
@@ -15,12 +16,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import ModelConfig, block_period, layer_kinds
+from repro.configs.base import (ModelConfig, layer_kinds, n_blocks,
+                                period_kinds)
 from .attention import (apply_attn, apply_attn_paged,
                         apply_attn_paged_prefill, init_attn, init_kv_cache)
 from .layers import apply_dense_ffn, dense_init, init_dense_ffn, rms_norm
 from .mamba import apply_mamba, init_mamba, init_ssm_cache
-from .moe import apply_moe, init_moe
+from .moe import apply_moe, apply_moe_dropless, init_moe
 
 __all__ = [
     "init_lm", "lm_loss", "lm_prefill", "lm_decode_step",
@@ -91,7 +93,16 @@ def _init_layer(cfg: ModelConfig, kind, key) -> Dict:
     return p
 
 
-def _apply_layer(cfg, kind, p, x, positions, *, mode, cache, window, aux):
+def _zero_stats(cfg) -> Dict:
+    """The stack's running sums: the auxiliary loss, and for a dropless
+    expert share the assignments its held experts computed."""
+    stats = {"aux": jnp.zeros((), jnp.float32)}
+    if cfg.moe_dropless and any(f == "moe" for _, f in layer_kinds(cfg)):
+        stats["moe_rows_held"] = jnp.zeros((), jnp.int32)
+    return stats
+
+
+def _apply_layer(cfg, kind, p, x, positions, *, mode, cache, window, stats):
     mixer, ffn = kind
     new_cache = None
     if mixer == "attn":
@@ -103,12 +114,20 @@ def _apply_layer(cfg, kind, p, x, positions, *, mode, cache, window, aux):
     if ffn == "dense":
         x = apply_dense_ffn(p["ffn"], x, cfg.norm_eps)
     elif ffn == "moe":
-        x, a = apply_moe(p["moe"], cfg, x, cfg.norm_eps)
-        aux = aux + a
-    return x, new_cache, aux
+        if cfg.moe_dropless:
+            x, a, rows = apply_moe_dropless(p["moe"], cfg, x, cfg.norm_eps)
+            stats = dict(stats, moe_rows_held=stats["moe_rows_held"] + rows)
+        else:
+            x, a = apply_moe(p["moe"], cfg, x, cfg.norm_eps)
+        stats = dict(stats, aux=stats["aux"] + a)
+    return x, new_cache, stats
 
 
 def _attn_specs(cfg) -> Dict:
+    if cfg.attn_kind == "mla":
+        return {"ln": P(None), "wq": P(None, "model"),
+                "wkv_a": P(None, None), "latent": {"ln": P(None)},
+                "wkv_b": P(None, "model"), "wo": P("model", None)}
     sp = {
         "ln": P(None),
         "wq": P(None, "model"), "wk": P(None, "model"), "wv": P(None, "model"),
@@ -177,39 +196,49 @@ def _prepend(spec: P, axis) -> P:
 # model init / specs
 # ---------------------------------------------------------------------------
 
+def _lead_kinds(cfg: ModelConfig):
+    return layer_kinds(cfg)[:cfg.first_k_dense]
+
+
 def init_lm(cfg: ModelConfig, key) -> Dict:
-    period = block_period(cfg)
-    kinds = layer_kinds(cfg)[:period]
-    n_blocks = cfg.n_layers // period
+    kinds = period_kinds(cfg)
+    period, nb = len(kinds), n_blocks(cfg)
     dt = jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, period + 3)
     blocks = []
     for pi, kind in enumerate(kinds):
-        bkeys = jax.random.split(keys[pi], n_blocks)
+        bkeys = jax.random.split(keys[pi], nb)
         blocks.append(jax.vmap(functools.partial(_init_layer, cfg, kind))(bkeys))
-    return {
+    params = {
         "embed": dense_init(keys[-3], (cfg.vocab_size, cfg.d_model), 1, dt),
         "blocks": tuple(blocks),
         "final_ln": jnp.zeros((cfg.d_model,), dt),
         "lm_head": dense_init(keys[-2], (cfg.d_model, cfg.vocab_size), 0, dt),
     }
+    if cfg.first_k_dense:
+        lkeys = jax.random.split(keys[-1], cfg.first_k_dense)
+        params["lead"] = tuple(_init_layer(cfg, kind, k)
+                               for kind, k in zip(_lead_kinds(cfg), lkeys))
+    return params
 
 
 def lm_param_specs(cfg: ModelConfig) -> Dict:
-    period = block_period(cfg)
-    kinds = layer_kinds(cfg)[:period]
     blocks = []
-    for kind in kinds:
+    for kind in period_kinds(cfg):
         sp = _layer_specs(cfg, kind)
         blocks.append(jax.tree.map(
             lambda s: _prepend(s, None), sp,
             is_leaf=lambda s: isinstance(s, P)))
-    return {
+    specs = {
         "embed": P("model", None),
         "blocks": tuple(blocks),
         "final_ln": P(None),
         "lm_head": P(None, "model"),
     }
+    if cfg.first_k_dense:
+        specs["lead"] = tuple(_layer_specs(cfg, kind)
+                              for kind in _lead_kinds(cfg))
+    return specs
 
 
 # ---------------------------------------------------------------------------
@@ -225,36 +254,52 @@ def _embed_inputs(cfg, params, tokens, frontend_embeds):
 
 def _stack_scan(cfg, params, x, positions, *, mode, caches=None, window=0,
                 remat=True, unroll=False, remat_policy="full"):
-    """Scan the period-block stack.  Returns (x, new_caches, aux)."""
-    period = block_period(cfg)
-    kinds = layer_kinds(cfg)[:period]
+    """The leading dense layers, then the period-block scan.  ``caches`` is
+    a tuple: one cache per leading layer, then one stacked cache per period
+    position.  Returns (x, new_caches, stats) (:func:`_zero_stats`)."""
+    kinds = period_kinds(cfg)
+    k = cfg.first_k_dense
+
+    def remat_fn(fn):
+        if not (remat and mode == "train"):
+            return fn
+        if remat_policy == "dots":
+            # save matmul (incl. TP-all-reduced) outputs; recompute only
+            # elementwise ops — no collective recompute in backward (§Perf)
+            return jax.checkpoint(
+                fn, policy=jax.checkpoint_policies.dots_saveable)
+        return jax.checkpoint(fn)
+
+    stats = _zero_stats(cfg)
+    lead_caches = []
+    for i, kind in enumerate(_lead_kinds(cfg)):
+        def lead(x, stats, lp, c, kind=kind):
+            x, nc, stats = _apply_layer(cfg, kind, lp, _seq_constrain(x),
+                                        positions, mode=mode, cache=c,
+                                        window=window, stats=stats)
+            return x, nc if nc is not None else 0.0, stats
+        x, nc, stats = remat_fn(lead)(
+            x, stats, params["lead"][i], None if caches is None else caches[i])
+        lead_caches.append(nc)
 
     def body(carry, xs):
-        x, aux = carry
+        x, stats = carry
         block_params, block_caches = xs
         new_caches = []
         for pi, kind in enumerate(kinds):
             c = None if block_caches is None else block_caches[pi]
             x = _seq_constrain(x)
             bp = _fsdp_constrain(block_params[pi], pi)
-            x, nc, aux = _apply_layer(cfg, kind, bp, x, positions,
-                                      mode=mode, cache=c, window=window, aux=aux)
+            x, nc, stats = _apply_layer(cfg, kind, bp, x, positions,
+                                        mode=mode, cache=c, window=window,
+                                        stats=stats)
             new_caches.append(nc if nc is not None else 0.0)
-        return (x, aux), tuple(new_caches)
+        return (x, stats), tuple(new_caches)
 
-    if remat and mode == "train":
-        if remat_policy == "dots":
-            # save matmul (incl. TP-all-reduced) outputs; recompute only
-            # elementwise ops — no collective recompute in backward (§Perf)
-            body = jax.checkpoint(
-                body, policy=jax.checkpoint_policies.dots_saveable)
-        else:
-            body = jax.checkpoint(body)
-
-    xs = (params["blocks"], caches)
-    (x, aux), new_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), xs, unroll=unroll)
-    return x, new_caches, aux
+    xs = (params["blocks"], None if caches is None else tuple(caches[k:]))
+    (x, stats), new_caches = jax.lax.scan(
+        remat_fn(body), (x, stats), xs, unroll=unroll)
+    return x, tuple(lead_caches) + tuple(new_caches), stats
 
 
 def _logits(cfg, params, x):
@@ -263,17 +308,19 @@ def _logits(cfg, params, x):
 
 
 def lm_loss(cfg: ModelConfig, params, batch, *, remat=True,
-            unroll=False, remat_policy="full") -> jax.Array:
-    """Next-token cross entropy.  batch: {tokens (B,S) int32,
-    [frontend (B,P,d)]}; loss predicts tokens[1:] from prefix."""
+            unroll=False, remat_policy="full", with_stats=False):
+    """Next-token cross entropy plus the auxiliary loss.  batch: {tokens
+    (B,S) int32, [frontend (B,P,d)]}; loss predicts tokens[1:] from prefix.
+    ``with_stats`` returns (loss, counters): ``moe_rows_held`` for a
+    dropless expert share, else nothing."""
     tokens = batch["tokens"]
     fe = batch.get("frontend")
     x = _embed_inputs(cfg, params, tokens, fe)
     B, S = x.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    x, _, aux = _stack_scan(cfg, params, x, positions, mode="train",
-                            remat=remat, unroll=unroll,
-                            remat_policy=remat_policy)
+    x, _, stats = _stack_scan(cfg, params, x, positions, mode="train",
+                              remat=remat, unroll=unroll,
+                              remat_policy=remat_policy)
     logits = _logits(cfg, params, x).astype(jnp.float32)
     # predict token t+1 at position t; frontend positions predict nothing.
     n_front = 0 if fe is None else fe.shape[1]
@@ -282,31 +329,31 @@ def lm_loss(cfg: ModelConfig, params, batch, *, remat=True,
     logz = jax.nn.logsumexp(pred, axis=-1)
     gold = jnp.take_along_axis(pred, tgt[..., None], axis=-1)[..., 0]
     nll = jnp.mean(logz - gold)
-    return nll + aux
+    loss = nll + stats.pop("aux")
+    return (loss, stats) if with_stats else loss
 
 
 def init_lm_cache(cfg: ModelConfig, batch: int, length: int):
-    """Cache pytree matching the block structure: tuple over period positions
-    of stacked (n_blocks, ...) leaves."""
-    period = block_period(cfg)
-    kinds = layer_kinds(cfg)[:period]
-    n_blocks = cfg.n_layers // period
-    caches = []
-    for mixer, _ in kinds:
+    """Cache pytree matching the stack: one cache per leading dense layer,
+    then a tuple over period positions of stacked (n_blocks, ...) leaves."""
+    nb = n_blocks(cfg)
+    caches = [init_kv_cache(cfg, batch, length) for _ in _lead_kinds(cfg)]
+    for mixer, _ in period_kinds(cfg):
         if mixer == "attn":
             one = init_kv_cache(cfg, batch, length)
         else:
             one = init_ssm_cache(cfg, batch)
         caches.append(jax.tree.map(
-            lambda l: jnp.broadcast_to(l[None], (n_blocks,) + l.shape), one))
+            lambda l: jnp.broadcast_to(l[None], (nb,) + l.shape), one))
     return tuple(caches)
 
 
 def lm_cache_specs(cfg: ModelConfig) -> Tuple:
-    period = block_period(cfg)
-    kinds = layer_kinds(cfg)[:period]
-    specs = []
-    for mixer, _ in kinds:
+    # (B, S, K, hd): batch over 'data', kv heads over 'model'
+    specs = [{"k": P("data", None, "model", None),
+              "v": P("data", None, "model", None)}
+             for _ in _lead_kinds(cfg)]
+    for mixer, _ in period_kinds(cfg):
         if mixer == "attn":
             # (n_blocks, B, S, K, hd): batch over 'data', kv heads over 'model'
             one = {"k": P(None, "data", None, "model", None),
@@ -324,9 +371,6 @@ def lm_prefill(cfg: ModelConfig, params, tokens, frontend_embeds=None,
     x = _embed_inputs(cfg, params, tokens, frontend_embeds)
     B, S = x.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    period = block_period(cfg)
-    kinds = layer_kinds(cfg)[:period]
-    n_blocks = cfg.n_layers // period
     # prefill needs per-layer caches as scan *outputs*; mamba still needs a
     # zero initial state, so pass explicit empty caches where required.
     caches = init_lm_cache(cfg, B, S if not window else window)
@@ -365,10 +409,7 @@ def lm_decode_step_paged(cfg: ModelConfig, params, pools, token, positions,
     x = jnp.take(params["embed"], token, axis=0)
     B = token.shape[0]
     pos2 = positions.reshape(B, 1).astype(jnp.int32)
-    period = block_period(cfg)
-    kinds = layer_kinds(cfg)[:period]
-    assert all(mixer == "attn" for mixer, _ in kinds), \
-        "paged decode covers attention mixers only (DESIGN §10 scope note)"
+    kinds = _check_attn_only(cfg)
 
     def body(carry, xs):
         x, aux = carry
@@ -395,9 +436,11 @@ def lm_decode_step_paged(cfg: ModelConfig, params, pools, token, positions,
 
 
 def _check_attn_only(cfg):
-    kinds = layer_kinds(cfg)[:block_period(cfg)]
+    kinds = period_kinds(cfg)
     assert all(mixer == "attn" for mixer, _ in kinds), \
         "paged serving covers attention mixers only (DESIGN §10 scope note)"
+    assert cfg.attn_kind == "gqa" and not cfg.first_k_dense, \
+        "paged serving covers GQA stacks without leading dense layers"
     return kinds
 
 

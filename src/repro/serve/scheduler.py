@@ -228,6 +228,13 @@ class ContinuousBatchingEngine:
                  attn_impl: str = "ref", prefill_chunk: Optional[int] = None,
                  max_step_tokens: Optional[int] = None,
                  prefill_cache_cap: int = 8):
+        if model.cfg.attn_kind != "gqa" or model.cfg.first_k_dense:
+            raise NotImplementedError(
+                f"{model.cfg.name}: the paged engine serves GQA stacks "
+                f"without leading dense layers; attn_kind="
+                f"{model.cfg.attn_kind!r} with first_k_dense="
+                f"{model.cfg.first_k_dense} needs a latent paged cache, "
+                f"which it does not have")
         assert model.decode_window == pcfg.window, \
             (model.decode_window, pcfg.window)
         self.model, self.params, self.pcfg = model, params, pcfg

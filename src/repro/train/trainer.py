@@ -554,12 +554,18 @@ def build_train_step(model: Model, run: RunConfig, topo,
                               mix=mix, **kw)
 
     def agent_loss(params, batch):
-        kw = {}
-        if model.cfg.family != "encdec":
-            kw["remat_policy"] = run.remat_policy
-        return model.loss(params, batch, remat=run.remat, **kw)
+        """(loss, counters): the model's step counters (``moe_rows_held``
+        of a dropless expert share), summed over agents into the step's
+        metrics."""
+        if model.cfg.family == "encdec":
+            return model.loss(params, batch, remat=run.remat), {}
+        return model.loss(params, batch, remat=run.remat,
+                          remat_policy=run.remat_policy, with_stats=True)
 
-    grad_fn = jax.vmap(jax.value_and_grad(agent_loss))
+    grad_fn = jax.vmap(jax.value_and_grad(agent_loss, has_aux=True))
+
+    def counters(stats):
+        return {k: jnp.sum(v) for k, v in stats.items()}
 
     lr_sched = None
     if run.warmup_steps or run.total_steps:
@@ -631,7 +637,7 @@ def build_train_step(model: Model, run: RunConfig, topo,
             with jax.named_scope("bus_unpack"):
                 params_tree = parambus.unpack_tree(layout, phi)
             with jax.named_scope("grad"):
-                losses, grads = grad_fn(params_tree, batch)
+                (losses, stats), grads = grad_fn(params_tree, batch)
                 grads = scaled_grads(grads, state["step"])
             with jax.named_scope("bus_pack"):
                 g_bus = pin_bus(parambus.pack_tree(layout, grads))
@@ -656,6 +662,7 @@ def build_train_step(model: Model, run: RunConfig, topo,
                     "loss": jnp.mean(losses),
                     "consensus": consensus_of(x_mixed),
                     "grad_norm": bus_grad_norm(g_bus),
+                    **counters(stats),
                 }
             return {"params": x_mixed, "opt": new_opt, "pipeline": new_pipe,
                     "step": state["step"] + 1}, metrics
@@ -667,7 +674,7 @@ def build_train_step(model: Model, run: RunConfig, topo,
             params_tree = (parambus.unpack_tree(layout, state["params"])
                            if packed else state["params"])
         with jax.named_scope("grad"):
-            losses, grads = grad_fn(params_tree, batch)
+            (losses, stats), grads = grad_fn(params_tree, batch)
             grads = scaled_grads(grads, state["step"])
         with jax.named_scope("bus_pack"):
             g_in = (pin_bus(parambus.pack_tree(layout, grads)) if packed
@@ -708,6 +715,7 @@ def build_train_step(model: Model, run: RunConfig, topo,
                 "loss": jnp.mean(losses),
                 "consensus": consensus,
                 "grad_norm": grad_norm,
+                **counters(stats),
             }
         return {"params": new_params, "opt": new_opt,
                 "step": state["step"] + 1}, metrics

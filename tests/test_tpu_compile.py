@@ -309,3 +309,28 @@ def test_bus_consensus_full_width_fits_one_chip(one_chip, mosaic):
     bus_bytes = 2 * _smollm_bus_rows(2) * 128 * 4
     assert c.memory_analysis().temp_size_in_bytes < (64 << 20)
     assert _bytes(c) <= bus_bytes + (64 << 20) < HBM_BYTES
+
+
+def test_expert_share_grouped_matmuls_at_published_widths(one_chip, mosaic):
+    """DeepSeek-V2-Lite's MoE layer at one chip's share (8 of 64 experts,
+    4096 tokens, top-6): the forward and backward of the grouped matmuls
+    compile to the megablox kernels, three ``gmm`` forward, three for the
+    rows' cotangent and three ``tgmm`` for the weights'."""
+    from collections import Counter
+
+    from repro.configs import get_config
+    from repro.models.moe import apply_moe_dropless, init_moe
+
+    cfg = get_config("deepseek_v2_lite")
+    shapes = jax.eval_shape(lambda k: init_moe(k, cfg), jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), shapes)
+    x = _sds(one_chip, (1, 4096, cfg.d_model), jnp.bfloat16)
+
+    def loss(p, x):
+        y, aux, _ = apply_moe_dropless(p, cfg, x, cfg.norm_eps)
+        return jnp.sum(y.astype(jnp.float32)) + aux
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), p, x).as_text()
+    kernels = Counter(re.findall(r"%(t?gmm)\.\d+ = [^\n]*tpu_custom_call",
+                                 text))
+    assert kernels == {"gmm": 6, "tgmm": 3}, kernels
