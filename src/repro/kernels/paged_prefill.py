@@ -3,8 +3,8 @@
 One fixed-size prompt chunk of ONE slot (DESIGN §11): ``C`` query tokens
 starting at absolute position ``chunk_start`` attend causally to (a) the
 slot's **previously-filled pages**, read straight from the page pool
-through page-table indirection exactly as in
-:mod:`repro.kernels.paged_attention`, and (b) the **in-flight chunk's own
+through page-table indirection in the BlockSpec index map, as the grid
+walk of :mod:`repro.kernels.paged_attention` does, and (b) the **in-flight chunk's own
 keys/values**, which at kernel time have not been scattered into the pool
 yet (attend-then-write — in ring mode the chunk overwrites ring rows that
 earlier chunk queries must still see) and therefore ride in as dense

@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
+from repro.kernels.paged_attention import GRID_MAX_PAGES, pages_per_block
 from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.serve.engine import greedy_generate, grow_caches, serve_param_specs
@@ -177,36 +178,99 @@ def test_paged_never_reads_unallocated_pages():
                                   np.asarray(dirty, np.float32)[live])
 
 
-def test_paged_kernel_matches_oracle_ragged():
-    """Pallas kernel vs the gather+sdpa oracle on a ragged batch with an
-    idle slot, GQA head-sharing and NaN-poisoned unallocated pages."""
+def _edges(page_size, ppb, full):
+    """kv lengths at the page and block edges, and a full slot."""
+    block = ppb * page_size
+    return [0, 1, page_size - 1, page_size, block - 1, block, block + 1, full]
+
+
+# the copying walk: hd 128, K 4, G 9 (starcoder2_7b's 36 / 4), page 64
+_COPY = dict(K=4, G=9, hd=128, page=64, n_pages=10)
+_COPY_PPB = pages_per_block(page_size=64, hd=128, n_kv_heads=4, n_pages=10,
+                            itemsize=4)
+# the grid walk: smollm_360m's K 5 × hd 64, page 16
+_GRID = dict(K=5, G=3, hd=64, page=16, n_pages=20)
+_GRID_PPB = min(GRID_MAX_PAGES, pages_per_block(
+    page_size=16, hd=64, n_kv_heads=5, n_pages=20, itemsize=4))
+
+PAGED_ORACLE_CASES = {
+    # idle slot 1; full slot 2
+    "ragged": dict(K=2, G=3, hd=16, page=8, n_pages=3, kv=[5, 0, 24, 17]),
+    "copy_walk_edges": dict(**_COPY, kv=_edges(64, _COPY_PPB, 640)),
+    "grid_walk_edges": dict(**_GRID, kv=_edges(16, _GRID_PPB, 320)),
+    # entries past the used prefix point at a poisoned page, not NULL, and
+    # rows past kv_len in the last used page are poisoned too
+    "copy_walk_poisoned_tail": dict(**_COPY, kv=_edges(64, _COPY_PPB, 640),
+                                    tail="poison"),
+    "grid_walk_poisoned_tail": dict(**_GRID, kv=_edges(16, _GRID_PPB, 320),
+                                    tail="poison"),
+    # entries past the used prefix (an idle slot's first among them) name a
+    # page past the pool: the interpreter raises on any fetch of one
+    "copy_walk_out_of_range_tail": dict(**_COPY, kv=_edges(64, _COPY_PPB,
+                                                           640),
+                                        tail="out_of_range"),
+    "grid_walk_out_of_range_tail": dict(**_GRID, kv=_edges(16, _GRID_PPB,
+                                                           320),
+                                        tail="out_of_range"),
+    # ring mode: kv_len = min(length, window), the slot's ring pages
+    "ring": dict(**{**_COPY, "n_pages": 8},
+                 kv=np.minimum([700, 300, 0, 2000], 8 * 64).tolist()),
+}
+
+
+@pytest.mark.parametrize("case", PAGED_ORACLE_CASES)
+def test_paged_kernel_matches_oracle_ragged(case):
+    """Pallas kernel vs the gather+sdpa oracle on a ragged batch with idle
+    slots, GQA head-sharing and NaN-poisoned unallocated pages, with kv
+    lengths at the page and block edges of both walks (copying, hd 128;
+    grid, hd 64).  The TPU interpreter raises on an out-of-bounds fetch and
+    fills fresh VMEM with NaN, so a buffer row the walk did not fill must
+    be masked too."""
+    from jax.experimental.pallas import tpu as pltpu
+
     from repro.kernels.ops import paged_attention
     from repro.kernels.ref import paged_attention_ref
 
+    c = PAGED_ORACLE_CASES[case]
+    K, G, hd, page_size, n_pages = (c[k] for k in
+                                    ("K", "G", "hd", "page", "n_pages"))
+    tail = c.get("tail", "null")
+    kv_len = np.array(c["kv"], np.int32)
+    B = len(kv_len)
+    n_used = -(-kv_len // page_size)
+    num_pages = 1 + int(n_used.sum()) + 4            # 4 spare, unallocated
     rng = np.random.default_rng(0)
-    B, K, G, hd = 4, 2, 3, 16
-    page_size, num_pages, n_pages = 8, 12, 3
     q = jnp.asarray(rng.normal(size=(B, K, G, hd)).astype(np.float32))
     kp = rng.normal(size=(K, num_pages, page_size, hd)).astype(np.float32)
     vp = rng.normal(size=(K, num_pages, page_size, hd)).astype(np.float32)
-    kv_len = np.array([5, 0, 24, 17], np.int32)     # idle slot 1; full slot 2
-    pt = np.zeros((B, n_pages), np.int32)
-    used = {0: [1], 2: [2, 3, 4], 3: [5, 6, 7]}
-    for b, pages in used.items():
-        pt[b, :len(pages)] = pages
-    alloc = {p for ps in used.values() for p in ps}
-    for p in range(num_pages):
-        if p not in alloc:
-            kp[:, p] = np.nan
-            vp[:, p] = np.nan
+    pt = np.full((B, n_pages), {"null": 0, "poison": num_pages - 1,
+                                "out_of_range": num_pages + 1000}[tail],
+                 np.int32)
+    first = 1
+    for b, n in enumerate(n_used):
+        pt[b, :n] = np.arange(first, first + n)
+        first += n
+        if tail == "poison" and kv_len[b] % page_size:
+            last = pt[b, n - 1]
+            kp[:, last, kv_len[b] % page_size:] = np.nan
+            vp[:, last, kv_len[b] % page_size:] = np.nan
+    kp[:, first:] = np.nan                           # unallocated pages
+    vp[:, first:] = np.nan
     kp, vp = jnp.asarray(kp), jnp.asarray(vp)
-    pt_j, len_j = jnp.asarray(pt), jnp.asarray(kv_len)
-    out = paged_attention(q, kp, vp, pt_j, len_j, page_size=page_size)
+    len_j = jnp.asarray(kv_len)
+    try:
+        out = paged_attention(q, kp, vp, jnp.asarray(pt), len_j,
+                              page_size=page_size,
+                              interpret=pltpu.InterpretParams())
+        out.block_until_ready()
+    finally:   # a fault inside the interpreter leaves its state behind
+        pltpu.reset_tpu_interpret_mode_state()
     ref = paged_attention_ref(q, jnp.nan_to_num(kp), jnp.nan_to_num(vp),
-                              pt_j, len_j, page_size=page_size)
-    live = np.array([0, 2, 3])
+                              jnp.asarray(np.minimum(pt, num_pages - 1)),
+                              len_j, page_size=page_size)
+    live = kv_len > 0
     assert jnp.isfinite(out).all()
-    assert (out[1] == 0).all()                       # idle slot: zero tile
+    assert (np.asarray(out)[~live] == 0).all()       # idle: zero tile
     assert np.allclose(np.asarray(out)[live], np.asarray(ref)[live],
                        atol=1e-5)
 

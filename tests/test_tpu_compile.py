@@ -143,10 +143,22 @@ def test_gossip_axpy_q8_flat(one_chip):
 PAGED_WIDTHS = [(5, 64), (8, 128)]
 
 
-@pytest.mark.parametrize("K,hd", PAGED_WIDTHS)
-def test_paged_attention(one_chip, K, hd):
-    from repro.kernels.paged_attention import paged_attention_kernel_call
-    B, G, page, num_pages, n_pages = 8, 3, 16, 33, 4
+VMEM_LIMIT = 16 << 20          # Mosaic's default scoped VMEM on a v5e
+
+# (slots, kv heads, group, head dim, page, pool pages, pages a slot, pages
+# a block): the PAGED_WIDTHS at a small pool (a slot is one block), and
+# starcoder2_7b's serving cell (1 MiB blocks)
+PAGED_DECODE = [pytest.param(8, K, 3, hd, 16, 33, 4, 4, id=f"{K}-{hd}")
+                for K, hd in PAGED_WIDTHS] + [
+    pytest.param(16, 4, 9, 128, 64, 1400, 64, 8, id="starcoder2_7b")]
+
+
+@pytest.mark.parametrize("B,K,G,hd,page,num_pages,n_pages,block",
+                         PAGED_DECODE)
+def test_paged_attention(one_chip, B, K, G, hd, page, num_pages, n_pages,
+                         block):
+    from repro.kernels.paged_attention import (paged_attention_kernel_call,
+                                               pages_per_block)
     bf = jnp.bfloat16
     c = _compile(
         lambda q, k, v, pt, ln: paged_attention_kernel_call(
@@ -157,6 +169,11 @@ def test_paged_attention(one_chip, K, hd):
         _sds(one_chip, (B, n_pages), jnp.int32),
         _sds(one_chip, (B,), jnp.int32))
     assert _kernels(c) == {"paged_attention"}
+    ppb = pages_per_block(page_size=page, hd=hd, n_kv_heads=K,
+                          n_pages=n_pages, itemsize=2)
+    assert ppb == block
+    # two buffers of a block of k and v pages, every kv head
+    assert 2 * 2 * ppb * K * page * hd * 2 <= VMEM_LIMIT
 
 
 @pytest.mark.parametrize("K,hd", PAGED_WIDTHS)
